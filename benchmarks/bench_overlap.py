@@ -71,6 +71,7 @@ import numpy as np
 
 from repro.core import DFLConfig, RoundExecutor, init_state, ring, \
     stack_round_batches
+from repro.core.substrate import make_mesh
 from repro.launch.roofline import Roofline, collective_bytes_from_hlo, \
     predict_overlap
 from repro.optim import sgd
@@ -90,7 +91,7 @@ def quad_loss(p, b, k=None):
 
 def make_executor(dim: int, tau_max: int, overlap: str = None):
     cfg = DFLConfig(tau1=tau_max, tau2=tau_max, topology=ring(N))
-    mesh = jax.make_mesh((N,), ("data",))
+    mesh = make_mesh((N,), ("data",))
     kw = {} if overlap is None else {"overlap": overlap}
     return RoundExecutor(cfg, quad_loss, sgd(3e-2), engine="sparse",
                          mesh=mesh, node_axes=("data",), donate=False, **kw)

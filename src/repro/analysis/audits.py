@@ -271,6 +271,7 @@ def build_audit_executor(num_nodes: int = 8, *, tau1_max: int = 3,
 
     from repro.core import DFLConfig, init_state, make_round_fn  # noqa: F401
     from repro.core.executor import RoundExecutor, stack_round_batches
+    from repro.core.substrate import make_mesh
     from repro.core.topology import ring
     from repro.optim import sgd
 
@@ -279,7 +280,7 @@ def build_audit_executor(num_nodes: int = 8, *, tau1_max: int = 3,
             f"audit superstep needs {num_nodes} devices, have "
             f"{len(jax.devices())} — run via `python -m repro.analysis "
             "audit` (it forces host devices) or set XLA_FLAGS")
-    mesh = jax.make_mesh((num_nodes,), ("data",))
+    mesh = make_mesh((num_nodes,), ("data",))
     topo = ring(num_nodes)
     cfg = DFLConfig(tau1=tau1_max, tau2=tau2_max, topology=topo)
     opt = sgd(0.1)

@@ -66,12 +66,13 @@ def _dotted(node: ast.AST) -> Optional[str]:
 # ---------------------------------------------------------------------------
 
 # Version-sensitive JAX APIs: each spelling below changed (or appeared)
-# across the supported 0.4.37 -> current range. core/substrate.py is the
-# ONE module allowed to touch them; everything else uses its wrappers.
+# across JAX releases. core/substrate.py is the ONE module allowed to touch
+# them; everything else uses its wrappers.
 _COMPAT_ATTRS = {
-    "jax.lax.axis_size",    # absent on 0.4.37
+    "jax.lax.axis_size",
     "lax.axis_size",
-    "jax.shard_map",        # top-level alias is >= 0.6 only
+    "jax.shard_map",
+    "jax.make_mesh",        # default axis types changed (Auto -> Explicit)
 }
 _COMPAT_IMPORT_MODULES = ("jax.experimental.shard_map",)
 _COMPAT_KWARGS = {"check_rep", "check_vma"}  # renamed across versions
@@ -262,7 +263,7 @@ RULES: Dict[str, Rule] = {
     for r in [
         Rule(
             "compat-boundary",
-            "Version-sensitive JAX APIs (shard_map, axis_size, "
+            "Version-sensitive JAX APIs (make_mesh, shard_map, axis_size, "
             "check_rep/check_vma, psum(1, axis), hasattr probes) only in "
             "core/substrate.py; everything else goes through its wrappers.",
             _check_compat_boundary,

@@ -26,12 +26,26 @@ SHAPES: Dict[str, InputShape] = {
 
 
 @dataclasses.dataclass(frozen=True)
+class ChipShare:
+    """A published config cut to what one chip of a stated deployment
+    holds. Every width stays as published; ``reduced`` names the keys that
+    were cut, and ``deployment`` says where the rest would live."""
+
+    model: ModelConfig
+    source: str                   # the published config it was cut from
+    reduced: Tuple[str, ...]      # ModelConfig keys changed from ``source``
+    deployment: str
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     """An assigned architecture + its production mapping."""
 
     arch_id: str
     model: ModelConfig
     reduced: ModelConfig          # smoke-test variant (<=2 periods, d<=512)
+    # published widths cut to one chip's share (``train.py --no-reduced``)
+    chip_share: Optional[ChipShare] = None
     # DFL node mapping (see DESIGN.md section 3):
     #   gossip-dp   — node axis = mesh data axis (16 / 32 divergent replicas)
     #   gossip-fsdp — few replicated nodes; weights FSDP x TP sharded

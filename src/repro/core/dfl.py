@@ -33,8 +33,8 @@ dense-vs-sparse parity exact even for stochastic losses/compressors):
 local step t key = fold_in(fold_in(round_key, 0), t); gossip step t key =
 fold_in(fold_in(round_key, 1), t); per-node key = fold_in(step_key, node).
 
-Supported JAX: 0.4.37 (pinned) and newer — version drift is absorbed by
-``repro.core.substrate``, never handled here.
+JAX API details are absorbed by ``repro.core.substrate``, never handled
+here.
 """
 from __future__ import annotations
 
@@ -68,6 +68,7 @@ __all__ = [
     "round_keys",
     "make_round_fn",
     "make_pipeline_fns",
+    "resolve_engine",
     "pipeline_round_body",
     "pipeline_drain_body",
     "round_wire_bits",
@@ -518,14 +519,7 @@ def make_round_fn(
             raise ValueError(
                 "participation masks index cfg.topology.edges(); a "
                 "round-varying topology schedule has no stable edge list")
-    if engine == "auto":
-        if population is not None:
-            # the population exceeds what any mesh enumerates: nodes must
-            # be data, not hardware (docs/ARCHITECTURE.md engine rules).
-            engine = "batched"
-        else:
-            engine = "sparse" if sparse_engine_eligible(
-                cfg, mesh, node_axes) else "dense"
+    engine = resolve_engine(engine, cfg, mesh, node_axes, population)
     if engine == "batched":
         if population is None:
             raise ValueError(
@@ -735,9 +729,7 @@ def make_pipeline_fns(
         raise ValueError(
             "participation masks index cfg.topology.edges(); a "
             "round-varying topology schedule has no stable edge list")
-    if engine == "auto":
-        engine = "sparse" if sparse_engine_eligible(
-            cfg, mesh, node_axes) else "dense"
+    engine = resolve_engine(engine, cfg, mesh, node_axes)
     if engine == "sparse":
         from repro.core.sharded import make_sharded_pipeline_fns
 
@@ -794,6 +786,22 @@ def make_pipeline_fns(
             return drain_body(state, buf, jnp.asarray(prev_tau2, jnp.int32))
 
     return pipe_fn, drain_fn
+
+
+def resolve_engine(engine: str, cfg: DFLConfig, mesh,
+                   node_axes: Sequence[str],
+                   population: Optional[int] = None) -> str:
+    """The engine that ``engine`` names: "auto" becomes "batched" when a
+    ``population`` is given (it exceeds what any mesh enumerates: nodes
+    are data, not hardware — docs/ARCHITECTURE.md engine rules), else
+    "sparse" when ``sparse_engine_eligible``, else "dense"; any other
+    name is returned as it is."""
+    if engine != "auto":
+        return engine
+    if population is not None:
+        return "batched"
+    return ("sparse" if sparse_engine_eligible(cfg, mesh, node_axes)
+            else "dense")
 
 
 def sparse_engine_eligible(cfg: DFLConfig, mesh,

@@ -51,6 +51,7 @@ both properties).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -60,7 +61,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dfl import (DFLConfig, DFLState, make_pipeline_fns,
-                            make_round_fn)
+                            make_round_fn, resolve_engine)
+from repro.core.sharded import node_state_sharding
 
 PyTree = Any
 
@@ -165,7 +167,9 @@ class RoundExecutor:
         1 <= tau1 <= cfg.tau1, 0 <= tau2 <= cfg.tau2) and defaults in static
         mode.
       loss_fn, opt, constrain, engine, mesh, node_axes, use_kernels:
-        forwarded to ``core.dfl.make_round_fn``.
+        forwarded to ``core.dfl.make_round_fn``; ``engine="auto"`` is
+        resolved once here (``core.dfl.resolve_engine``) and the result
+        kept as ``self.engine``.
       dynamic: True (default) compiles the dynamic-tau round once; False is
         the keyed static fallback — one compile per distinct (tau1, tau2),
         cached.
@@ -229,8 +233,8 @@ class RoundExecutor:
         self.donate = donate
         self.num_nodes = cfg.topology.num_nodes
         self.num_edges = cfg.topology.num_edges
-        if engine == "auto" and population is not None:
-            engine = "batched"
+        engine = resolve_engine(engine, cfg, mesh, node_axes, population)
+        self.engine = engine
         self.batched = engine == "batched"
         if self.batched:
             if population is None:
@@ -269,6 +273,11 @@ class RoundExecutor:
                 "participation masks are schedule data on the dynamic "
                 "path; the static fallback keys compiles on (tau1, tau2) "
                 "and cannot express per-round masks")
+        # the sparse engine's state lives split over the node mesh axes;
+        # ``place`` puts every dispatched state there, so a state built on
+        # one device and one a dispatch returned trace the same superstep.
+        self.state_sharding = (node_state_sharding(cfg, mesh, node_axes)
+                               if engine == "sparse" else None)
         self._make_kw = dict(
             constrain=constrain, engine=engine, mesh=mesh,
             node_axes=tuple(node_axes), use_kernels=use_kernels)
@@ -278,7 +287,6 @@ class RoundExecutor:
         self._trace_count = 0
         self.dispatch_count = 0
         self.rounds_dispatched = 0
-        self._in_warmup = False
         self._static_cache: Dict[Tuple[int, int], Callable] = {}
         # host-work memo for the dispatch hot path: validated/padded
         # trajectory rows + their device array, keyed on the raw bytes
@@ -573,8 +581,21 @@ class RoundExecutor:
             lambda: self._check_trajectory(raw, k))
         return self._dispatch_prepared(state, batches, arr, dev, k)
 
+    def place(self, state: DFLState) -> DFLState:
+        """``state`` on this executor's engine layout (see
+        ``state_sharding``); a no-op for a state already there and on the
+        dense and batched engines. Every dispatch calls it, since a caller
+        may pass a state built anywhere; for a placed state ``device_put``
+        returns the same arrays, and the walk cost 0.92 ms median for the
+        29-leaf qwen3-1.7b state on 4 CPU devices — beside supersteps of
+        seconds at that model's published widths."""
+        if self.state_sharding is None:
+            return state
+        return jax.device_put(state, self.state_sharding)
+
     def _dispatch_prepared(self, state: DFLState, batches: PyTree,
                            arr: np.ndarray, dev, k: int):
+        state = self.place(state)
         self.dispatch_count += 1
         self.rounds_dispatched += k
         if self._tel is None:
@@ -584,14 +605,10 @@ class RoundExecutor:
         # On sync backends (this jaxlib's CPU client) the superstep
         # EXECUTES inside the call, so dur is real device time; on async
         # backends it is enqueue cost and the flush event carries the rest.
-        # Warmup dispatches are tagged apart so reports never conflate
-        # compile-warming with measured supersteps.
         dur = self._tel.now() - t0
-        prefix = "warmup-superstep" if self._in_warmup else "superstep"
-        self._tel.emit("superstep", track="dispatch", name=f"{prefix}-k{k}",
-                       t=t0, dur=dur, k=k,
-                       warmup=self._in_warmup, dispatch=self.dispatch_count)
-        if self.overlap == "pipeline" and not self._in_warmup:
+        self._tel.emit("superstep", track="dispatch", name=f"superstep-k{k}",
+                       t=t0, dur=dur, k=k, dispatch=self.dispatch_count)
+        if self.overlap == "pipeline":
             # the gossip slice riding under the compute slice: the stale
             # exchange of rounds [0, k) is in flight INSIDE this dispatch
             # window (drained before it returns), so the overlap track
@@ -656,30 +673,34 @@ class RoundExecutor:
     def warmup(self, state: DFLState, batches: PyTree,
                tau1: int = 1, tau2: int = 0) -> None:
         """Pay the trace+compile for this batch SHAPE (and, in static mode,
-        this (tau1, tau2) key) before any measured dispatch, on a throwaway
-        copy of ``state`` (donation consumes it) — on this jaxlib the CPU
-        client executes synchronously inside ``dispatch``, so a compile
-        occurring there would otherwise contaminate the measured window of
-        whatever round runs first at that shape (AOT ``lower().compile()``
-        does not populate the jit call cache on the 0.4.37 pin, hence a
-        real dummy dispatch). Dynamic mode compiles one executable per
-        shape, so the default minimal schedule (1, 0) is enough; static
-        mode must warm every (tau1, tau2) it will dispatch. Dispatch
-        statistics are left untouched."""
-        dummy = jax.tree_util.tree_map(jnp.copy, state)
-        n_dispatch, n_rounds = self.dispatch_count, self.rounds_dispatched
-        self._in_warmup = True
-        try:
-            if self._tel is not None:
-                with self._tel.span("warmup", track="dispatch"):
-                    out = self.dispatch(dummy, batches, tau1, tau2)
-                    jax.block_until_ready(out)
-            else:
-                out = self.dispatch(dummy, batches, tau1, tau2)
-                jax.block_until_ready(out)
-        finally:
-            self._in_warmup = False
-        self.dispatch_count, self.rounds_dispatched = n_dispatch, n_rounds
+        this (tau1, tau2) key) before any measured dispatch, so no measured
+        round contains a compile. Compiles ahead of time from the
+        arguments' shapes and shardings — nothing runs and no copy of
+        ``state`` is made, so warming a model that fills the device costs
+        no device memory. Dynamic mode compiles one executable per shape;
+        static mode must warm every (tau1, tau2) it will dispatch.
+        Dispatch statistics are left untouched."""
+        def spec(x):
+            # single-device and host arrays lower for the default device,
+            # which is where both fresh and dispatch-returned ones hit the
+            # cache.
+            sharding = getattr(x, "sharding", None)
+            if sharding is not None and len(sharding.device_set) == 1:
+                sharding = None
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+        k = jax.tree_util.tree_leaves(batches)[0].shape[0]
+        if self.dynamic:
+            fn = self._dynamic_fn
+            args = (batches, np.zeros((k, self.row_width), np.int32))
+        else:
+            tau1, tau2 = self._check_taus(tau1, tau2)
+            fn = self._static_fn(tau1, tau2)
+            args = (jax.tree_util.tree_map(lambda b: b[:, :tau1], batches),)
+        args = jax.tree_util.tree_map(spec, (self.place(state),) + args)
+        with (self._tel.span("warmup", track="dispatch")
+              if self._tel is not None else contextlib.nullcontext()):
+            fn.lower(*args).compile()
 
 
 class HostPrefetcher:

@@ -105,7 +105,11 @@ def mix_dense(
             cm = _as_mixing_array(topology, dtype)
         else:
             cm = masked_mixing_matrix(topology, edge_mask, dtype)
-        mixed = jnp.einsum("ji,j...->i...", cm, x.astype(cm.dtype))
+        # HIGHEST: a TPU contracts f32 at bf16 precision by default, which
+        # rounds weights such as 1/3 and leaves C no longer doubly
+        # stochastic.
+        mixed = jnp.einsum("ji,j...->i...", cm, x.astype(cm.dtype),
+                           precision=jax.lax.Precision.HIGHEST)
         return mixed.astype(x.dtype)
 
     del c

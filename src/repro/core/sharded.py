@@ -19,14 +19,14 @@ folding, metrics — is ``core.dfl.round_body`` running on a
 ``ShardedSubstrate``, i.e. the exact same code the dense engine executes,
 which is what keeps the engines from drifting apart again.
 
-Engine selection rule (applied by ``launch.steps`` / ``launch.train`` when
-engine="auto"): sparse iff ``cfg.topology.is_shift_structured()`` (circulant
+Engine selection rule (``core.dfl.resolve_engine`` when engine="auto";
+``launch.steps.select_engine`` adds its auto-axis guard): sparse iff ``cfg.topology.is_shift_structured()`` (circulant
 C: ring/torus rows of the mesh; includes the degenerate no-edge C = I),
 no dense-only features (schedules, dense_power), and the node mesh axes
 enumerate exactly the N > 1 nodes. The dense engine (``core.dfl``) remains
 the general-topology path and the numerical reference
 (tests/test_multidevice.py checks they agree bit-for-bit-ish, compressed
-and uncompressed). Supported JAX: 0.4.37 (pinned) and newer, via
+and uncompressed). JAX API details are absorbed by
 ``repro.core.substrate``.
 """
 from __future__ import annotations
@@ -42,6 +42,43 @@ from repro.core.dfl import (DFLConfig, DFLState, pipeline_drain_body,
 from repro.core.substrate import ShardedSubstrate
 
 PyTree = Any
+
+# How far apart the two engines' per-round losses may be on the same seed.
+# They add each gossip step's terms in a different order; with bf16
+# parameters an f32 rounding difference now and then flips a parameter by
+# one ulp, and the losses drift apart (with float32 parameters they are
+# bitwise equal). Readings on the reduced qwen3-1.7b, 4-node ring,
+# tau=(2, 2), 4 rounds, 8 init seeds, on CPU: at most 2.9e-4 at seq 32 and
+# 1.04e-4 at seq 256.
+ENGINE_PARITY_LOSS_ATOL = 5e-4
+
+
+def _node_entry(node_axes: Sequence[str]):
+    return tuple(node_axes) if len(node_axes) > 1 else node_axes[0]
+
+
+def node_state_specs(cfg: DFLConfig, node_axes: Sequence[str]) -> DFLState:
+    """The sparse engine's ``DFLState`` layout as PartitionSpec prefixes:
+    node-stacked trees split over ``node_axes``, key and counter
+    replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    node = P(_node_entry(node_axes))
+    return DFLState(params=node, opt_state=node,
+                    hat_params=node if cfg.is_compressed else None,
+                    rng=P(), round_idx=P())
+
+
+def node_state_sharding(cfg: DFLConfig, mesh,
+                        node_axes: Sequence[str]) -> DFLState:
+    """``node_state_specs`` as ``NamedSharding``s on ``mesh`` — the
+    ``jax.device_put`` / ``out_shardings`` target of a sparse-engine
+    state."""
+    from jax.sharding import NamedSharding
+
+    specs = node_state_specs(cfg, node_axes)
+    return DFLState(*(None if s is None else NamedSharding(mesh, s)
+                      for s in specs))
 
 
 def make_sharded_round_fn(
@@ -107,14 +144,8 @@ def make_sharded_round_fn(
         f"node mesh axes {tuple(node_axes)} enumerate {mesh_n} devices but "
         f"{topo.name} has {topo.num_nodes} nodes — the size-1-per-node "
         "shard_map layout would silently drop nodes")
-    node_entry = tuple(node_axes) if len(node_axes) > 1 else node_axes[0]
-    state_specs = DFLState(
-        params=P(node_entry),
-        opt_state=P(node_entry),
-        hat_params=P(node_entry) if cfg.is_compressed else None,
-        rng=P(),
-        round_idx=P(),
-    )
+    node_entry = _node_entry(node_axes)
+    state_specs = node_state_specs(cfg, node_axes)
     batch_spec = P(None, node_entry)
 
     def body(state: DFLState, batches: PyTree, taus=None, masks=None):
@@ -230,14 +261,8 @@ def make_sharded_pipeline_fns(
     assert mesh_n == topo.num_nodes, (
         f"node mesh axes {tuple(node_axes)} enumerate {mesh_n} devices but "
         f"{topo.name} has {topo.num_nodes} nodes")
-    node_entry = tuple(node_axes) if len(node_axes) > 1 else node_axes[0]
-    state_specs = DFLState(
-        params=P(node_entry),
-        opt_state=P(node_entry),
-        hat_params=P(node_entry) if cfg.is_compressed else None,
-        rng=P(),
-        round_idx=P(),
-    )
+    node_entry = _node_entry(node_axes)
+    state_specs = node_state_specs(cfg, node_axes)
     buf_spec = P(node_entry)
     batch_spec = P(None, node_entry)
     squeeze = lambda t: jax.tree_util.tree_map(lambda x: x[0], t)

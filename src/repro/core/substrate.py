@@ -1,17 +1,15 @@
-"""JAX version-compat shims + the node-axis substrate shared by both engines.
+"""The mesh constructor, ``shard_map`` helpers, and the node-axis
+substrate shared by every engine.
 
-Supported JAX: the pinned 0.4.37 (this container) up through current
-releases. Two API drifts are papered over here so the rest of the codebase
-never touches them again (``jax.lax.axis_size`` landing silently broke the
-whole sparse engine once — see tests/test_multidevice.py):
+Targets JAX 0.9. Two facts of that release are absorbed here so the rest
+of the codebase never touches them:
 
-  * ``jax.lax.axis_size``    — absent in 0.4.37; ``lax.psum(1, axis)`` is
-                               the portable spelling (returns a static int
-                               for a concrete operand inside shard_map).
-  * ``jax.shard_map``        — 0.4.37 only has
-                               ``jax.experimental.shard_map.shard_map`` with
-                               ``check_rep=``/``auto=``; newer JAX renames
-                               these to ``check_vma=``/``axis_names=``.
+  * ``jax.make_mesh`` defaults to *Explicit* axis types, under which
+    ``with_sharding_constraint`` on a batched value and vmaps over mixed
+    specs are refused. ``make_mesh`` below asks for ``AxisType.Auto``
+    (GSPMD-partitioned) axes, which is what every engine here assumes.
+  * ``jax.shard_map`` takes the manual axes as ``axis_names=``; the
+    ``shard_map`` wrapper below spells that as ``manual_axes``.
 
 The second half of the module is the *node substrate*: one small object
 that abstracts "the node axis" so the DFL algorithm (local-update scan,
@@ -43,6 +41,7 @@ PyTree = Any
 AxisName = Union[str, Tuple[str, ...]]
 
 __all__ = [
+    "make_mesh",
     "axis_size",
     "mesh_axis_size",
     "shard_map",
@@ -55,19 +54,28 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# Version compat
+# Meshes and shard_map
 # ---------------------------------------------------------------------------
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` — THE one mesh
+    constructor of this codebase. ``devices`` defaults to
+    ``jax.devices()`` (a described topology's devices work too)."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names),
+                         devices=devices)
 
 
 def axis_size(axis_name: AxisName) -> int:
     """Size of a named mesh axis (or product over a tuple of axes), valid
-    inside shard_map/pmap on every supported JAX version."""
+    inside shard_map."""
     if isinstance(axis_name, (tuple, list)):
         return int(np.prod([axis_size(a) for a in axis_name]))
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    # psum of a concrete scalar is evaluated statically: the axis size.
-    return int(jax.lax.psum(1, axis_name))
+    return int(jax.lax.axis_size(axis_name))
 
 
 def mesh_axis_size(mesh, axes: Optional[AxisName] = None) -> int:
@@ -97,39 +105,20 @@ def shard_map(
     manual_axes: Optional[Sequence[str]] = None,
     check: bool = False,
 ):
-    """``shard_map`` across the check_rep->check_vma / auto->axis_names
-    renames. ``manual_axes``: mesh axes the body is manual over (all axes
-    when None); the rest stay auto (GSPMD-partitioned)."""
-    if hasattr(jax, "shard_map"):  # JAX >= 0.6
-        import inspect
-
-        kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-        params = inspect.signature(jax.shard_map).parameters
-        kwargs["check_vma" if "check_vma" in params else "check_rep"] = check
-        if manual_axes is not None and set(manual_axes) != set(mesh.axis_names):
-            kwargs["axis_names"] = set(manual_axes)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs,
-              "check_rep": check}
+    """``jax.shard_map``. ``manual_axes``: mesh axes the body is manual
+    over (all axes when None); the rest stay auto (GSPMD-partitioned)."""
+    kwargs = {}
     if manual_axes is not None and set(manual_axes) != set(mesh.axis_names):
-        kwargs["auto"] = frozenset(mesh.axis_names) - set(manual_axes)
-    return _shard_map(f, **kwargs)
+        kwargs["axis_names"] = set(manual_axes)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check, **kwargs)
 
 
 def supports_partial_auto() -> bool:
-    """Whether shard_map with a non-trivial auto (GSPMD) axis set is usable.
-
-    The pinned jaxlib 0.4.37 hard-crashes compiling scan+ppermute bodies
-    under partial-manual shard_map when an auto axis has size > 1
-    (``Check failed: sharding.IsManualSubgroup()`` in hlo_sharding_util);
-    size-1 auto axes are fine. Newer JAX (with top-level ``jax.shard_map``)
-    handles partial-manual properly. Engine auto-selection consults this so
-    a tensor-parallel mesh falls back to the dense engine on the old pin
-    instead of aborting the process.
-    """
-    return hasattr(jax, "shard_map")
+    """Whether shard_map with a non-trivial auto (GSPMD) axis set is usable
+    — always, on the JAX this codebase targets. Engine auto-selection
+    consults it (``core.dfl.sparse_engine_eligible``)."""
+    return True
 
 
 # ---------------------------------------------------------------------------

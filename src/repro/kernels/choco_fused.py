@@ -101,7 +101,9 @@ def _choco_topk_kernel(gamma_ref, thresh_ref, x_ref, y_ref, my_ref, d_ref,
     x_new = x + gamma * (my - y)
     xout_ref[...] = x_new.astype(xout_ref.dtype)
     d = d_ref[...]
-    q = jnp.where(jnp.abs(d) >= thresh, d, jnp.zeros_like(d))
+    # exact f32 widening of the leaf-dtype comparison (topk._mask_kernel)
+    q = jnp.where(jnp.abs(d).astype(jnp.float32) >= thresh, d,
+                  jnp.zeros_like(d))
     yout_ref[...] = y_ref[...] + q
 
 
@@ -119,7 +121,7 @@ def choco_topk_2d(x2d: jnp.ndarray, y2d: jnp.ndarray, my2d: jnp.ndarray,
     flip a boundary element in or out of the kept set); ``thresh``:
     (1, 1) tile in the LEAF dtype, the k-th largest |d| (magnitude
     comparison in the dtype the reference compressor sorts, see
-    ``repro.kernels.topk``).
+    ``repro.kernels.topk``), widened to f32 on its way into the kernel.
     """
     rows, lanes = x2d.shape
     assert lanes == LANES and rows % BLOCK_ROWS == 0, x2d.shape
@@ -134,4 +136,4 @@ def choco_topk_2d(x2d: jnp.ndarray, y2d: jnp.ndarray, my2d: jnp.ndarray,
         out_shape=(jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
                    jax.ShapeDtypeStruct(x2d.shape, x2d.dtype)),
         interpret=interpret,
-    )(gamma, thresh, x2d, y2d, my2d, d2d)
+    )(gamma, thresh.astype(jnp.float32), x2d, y2d, my2d, d2d)

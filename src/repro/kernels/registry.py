@@ -15,21 +15,25 @@ kernel runs:
     bitwise contract.
   * ``resolve_mode(name, interpret)`` — the per-op dispatch rule:
 
-        explicit interpret=True   -> "interpret"  (Python-eval the kernel)
-        explicit interpret=False  -> "mosaic"     (force TPU compile)
-        None, off TPU             -> "interpret"
-        None, on TPU, op.mosaic   -> "mosaic"
-        None, on TPU, not mosaic  -> "fallback"   (plain-XLA reference
-                                     path; e.g. the TopK candidate pass
-                                     calls lax.top_k in-kernel, which
-                                     Mosaic does not lower)
+        explicit interpret=True               -> "interpret"  (Python-
+                                                 eval the kernel)
+        interpret=False or on TPU, op.mosaic  -> "mosaic"     (TPU
+                                                 compile)
+        interpret=False or on TPU, not mosaic -> "fallback"   (plain-XLA
+                                                 reference path; e.g. the
+                                                 TopK candidate pass calls
+                                                 lax.top_k in-kernel,
+                                                 which Mosaic does not
+                                                 lower)
+        None, off TPU                         -> "interpret"
 
   * ``parity_suite()`` — the reference-parity harness: every registered
-    op is run in interpret mode against its ``repro.kernels.ref`` oracle
-    over a shape/dtype sweep; ops with ``bitwise=True`` must match
-    EXACTLY. ``tests/test_kernels.py`` and ``benchmarks/bench_kernels``
-    both consume this, so a new kernel cannot land without a
-    mechanically-checked oracle.
+    op is run (in interpret mode, or compiled with ``interpret=False``)
+    against its ``repro.kernels.ref`` oracle over a shape/dtype sweep;
+    ops with ``bitwise=True`` must match EXACTLY.
+    ``tests/test_kernels.py``, ``benchmarks/bench_kernels`` and
+    ``chip_smoke.py`` (compiled, on the chip) consume this, so a new
+    kernel cannot land without a mechanically-checked oracle.
 """
 from __future__ import annotations
 
@@ -93,18 +97,19 @@ class KernelOp:
              TPU instead of crashing the compile).
     bitwise: the interpret-mode kernel must match its oracle EXACTLY
              (parity_suite enforces err == 0.0).
-    parity:  (key, shape, dtype) -> max |kernel - oracle| in f32, running
-             the kernel in interpret mode against the ref oracle.
+    parity:  (key, shape, dtype, interpret) -> max |kernel - oracle| in
+             f32, running the kernel (interpret mode unless
+             ``interpret=False``) against the ref oracle.
     """
 
     name: str
     mosaic: bool
     bitwise: bool
     doc: str
-    parity: Callable[[jax.Array, Tuple[int, ...], Any], float]
+    parity: Callable[[jax.Array, Tuple[int, ...], Any, bool], float]
 
 
-def _parity_qsgd(key, shape, dtype) -> float:
+def _parity_qsgd(key, shape, dtype, interpret) -> float:
     from repro.kernels import ops, ref
 
     k1, k2 = jax.random.split(key)
@@ -113,12 +118,12 @@ def _parity_qsgd(key, shape, dtype) -> float:
     d = int(np.prod(shape))
     s = 16.0
     c = 1.0 + min(d / (s * s), d ** 0.5 / s)
-    got = ops.qsgd_quantize(x, noise, levels=16, interpret=True)
+    got = ops.qsgd_quantize(x, noise, levels=16, interpret=interpret)
     want = ref.qsgd_ref(x, noise, levels=16, c=c)
     return _max_err(got, want)
 
 
-def _parity_gossip_mix(key, shape, dtype) -> float:
+def _parity_gossip_mix(key, shape, dtype, interpret) -> float:
     from repro.kernels import ops, ref
 
     deg = 2
@@ -126,32 +131,32 @@ def _parity_gossip_mix(key, shape, dtype) -> float:
     nbrs = jax.random.normal(jax.random.fold_in(key, 1),
                              (deg,) + tuple(shape)).astype(dtype)
     w = jnp.concatenate([jnp.asarray([0.5]), jnp.full((deg,), 0.25)])
-    got = ops.gossip_mix(x, nbrs, w, interpret=True)
+    got = ops.gossip_mix(x, nbrs, w, interpret=interpret)
     want = ref.gossip_mix_ref(x, nbrs, w)
     return _max_err(got, want)
 
 
-def _parity_choco_move(key, shape, dtype) -> float:
+def _parity_choco_move(key, shape, dtype, interpret) -> float:
     from repro.kernels import ops, ref
 
     x, y, my = (jax.random.normal(jax.random.fold_in(key, i),
                                   shape).astype(dtype) for i in range(3))
-    got = ops.choco_move(x, y, my, 0.37, interpret=True)
+    got = ops.choco_move(x, y, my, 0.37, interpret=interpret)
     want = ref.choco_move_ref(x, y, my, 0.37)
     return max(_max_err(got[0], want[0]), _max_err(got[1], want[1]))
 
 
-def _parity_topk(key, shape, dtype) -> float:
+def _parity_topk(key, shape, dtype, interpret) -> float:
     from repro.kernels import ops, ref
 
     x = jax.random.normal(key, shape).astype(dtype)
     k = max(1, int(np.prod(shape)) // 4)
-    got = ops.top_k_compress(x, k, interpret=True)
+    got = ops.top_k_compress(x, k, interpret=interpret)
     want = ref.top_k_ref(x, k)
     return _max_err(got, want)
 
 
-def _parity_topk_mask(key, shape, dtype) -> float:
+def _parity_topk_mask(key, shape, dtype, interpret) -> float:
     # the mask kernel ALONE against a hand-built threshold (what the TPU
     # fallback mode keeps as a compiled kernel), independent of the
     # candidate-select pass.
@@ -162,14 +167,14 @@ def _parity_topk_mask(key, shape, dtype) -> float:
     thresh = jnp.sort(jnp.abs(flat))[flat.size // 2]
     x2d, n = ops._to_2d(x)
     out2d = topk_mod.topk_mask_2d(x2d, thresh.reshape(1, 1),
-                                  interpret=True)
+                                  interpret=interpret)
     got = ops._from_2d(out2d, n, x.shape, x.dtype)
     want = jnp.where(jnp.abs(flat) >= thresh, flat,
                      0.0).reshape(x.shape).astype(x.dtype)
     return _max_err(got, want)
 
 
-def _parity_choco_qsgd(key, shape, dtype) -> float:
+def _parity_choco_qsgd(key, shape, dtype, interpret) -> float:
     from repro.kernels import ops, ref
 
     ks = [jax.random.fold_in(key, i) for i in range(4)]
@@ -179,18 +184,18 @@ def _parity_choco_qsgd(key, shape, dtype) -> float:
     s = 16.0
     c = 1.0 + min(d / (s * s), d ** 0.5 / s)
     got = ops.choco_qsgd_move(x, y, my, 0.5, noise, levels=16,
-                              interpret=True)
+                              interpret=interpret)
     want = ref.choco_qsgd_ref(x, y, my, 0.5, noise, levels=16, c=c)
     return max(_max_err(got[0], want[0]), _max_err(got[1], want[1]))
 
 
-def _parity_choco_topk(key, shape, dtype) -> float:
+def _parity_choco_topk(key, shape, dtype, interpret) -> float:
     from repro.kernels import ops, ref
 
     x, y, my = (jax.random.normal(jax.random.fold_in(key, i),
                                   shape).astype(dtype) for i in range(3))
     k = max(1, int(np.prod(shape)) // 4)
-    got = ops.choco_topk_move(x, y, my, 0.5, k, interpret=True)
+    got = ops.choco_topk_move(x, y, my, 0.5, k, interpret=interpret)
     want = ref.choco_topk_ref(x, y, my, 0.5, k)
     return max(_max_err(got[0], want[0]), _max_err(got[1], want[1]))
 
@@ -244,15 +249,15 @@ def resolve_mode(name: str, interpret: Optional[bool] = None) -> str:
 
     ``interpret=None`` (the default everywhere) resolves from the LAZILY
     detected backend and the op's Mosaic flag; an explicit bool always
-    wins (tests force interpret=True; a TPU power user may force
-    interpret=False to surface Mosaic lowering errors eagerly).
+    wins over the backend (tests force interpret=True; ``interpret=False``
+    compiles for the TPU wherever this runs, which is how the kernels are
+    compiled for a described chip). An op Mosaic cannot lower takes its
+    plain-XLA fallback whenever it is not interpreted.
     """
     op = get_op(name)
     if interpret is True:
         return "interpret"
-    if interpret is False:
-        return "mosaic"
-    if on_tpu():
+    if interpret is False or on_tpu():
         return "mosaic" if op.mosaic else "fallback"
     return "interpret"
 
@@ -276,8 +281,10 @@ def parity_suite(
     dtypes: Sequence[Any] = PARITY_DTYPES,
     seed: int = 0,
     ops: Optional[Sequence[str]] = None,
+    interpret: bool = True,
 ) -> List[Dict[str, Any]]:
-    """Run every registered op's interpret-mode kernel against its oracle.
+    """Run every registered op's kernel against its oracle: in interpret
+    mode, or compiled (``interpret=False``, on the chip).
 
     Returns one record per (op, shape, dtype):
     ``{"op", "shape", "dtype", "max_err", "bitwise", "ok"}`` where ``ok``
@@ -297,7 +304,7 @@ def parity_suite(
                 case = f"{name}:{tuple(shape)}".encode()
                 key = jax.random.key(
                     (seed * 7919 + zlib.crc32(case)) % 2 ** 31)
-                err = op.parity(key, tuple(shape), dtype)
+                err = op.parity(key, tuple(shape), dtype, interpret)
                 tol = 0.0 if op.bitwise else (
                     1e-5 if dtype == jnp.float32 else 1e-2)
                 records.append({

@@ -72,16 +72,18 @@ def topk_partials_2d(x2d: jnp.ndarray, *, cand: int,
 
 def _mask_kernel(thresh_ref, x_ref, out_ref):
     x = x_ref[...]
-    keep = jnp.abs(x) >= thresh_ref[0, 0]
+    # |x| widened to f32 is exact and keeps its order, so this is the
+    # leaf-dtype comparison; Mosaic extracts only 32-bit scalars.
+    keep = jnp.abs(x).astype(jnp.float32) >= thresh_ref[0, 0]
     out_ref[...] = jnp.where(keep, x, jnp.zeros_like(x))
 
 
 def topk_mask_2d(x2d: jnp.ndarray, thresh: jnp.ndarray, *,
                  interpret: bool = False) -> jnp.ndarray:
-    """Keep-or-zero against the threshold: all operands the input dtype.
+    """Keep-or-zero against the threshold, in the input dtype.
 
     ``thresh``: (1, 1) scalar tile — the k-th largest |x| from the select
-    pass. Keeps ``|x| >= thresh`` (ties INCLUSIVE, matching the reference
+    pass (in x's dtype; it enters the kernel widened to f32). Keeps ``|x| >= thresh`` (ties INCLUSIVE, matching the reference
     compressor — a few extra tied coordinates still satisfy Assumption 2).
     """
     rows, lanes = x2d.shape
@@ -97,4 +99,4 @@ def topk_mask_2d(x2d: jnp.ndarray, thresh: jnp.ndarray, *,
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=interpret,
-    )(thresh, x2d)
+    )(thresh.astype(jnp.float32), x2d)

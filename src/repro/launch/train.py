@@ -1,10 +1,12 @@
-"""DFL training CLI (runs for real at reduced scale; lowers-only at full).
+"""DFL training CLI.
 
-    PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b --reduced \
+    PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b \
         --nodes 4 --tau1 4 --tau2 4 --rounds 20 --batch 4 --seq 128
 
-Full-scale configs on the production mesh are exercised via dryrun.py; this
-driver actually executes on the host devices (CPU here, TPU unchanged).
+``--reduced`` (the default) trains the arch's small smoke-test variant;
+``--no-reduced`` trains its chip share (``ArchConfig.chip_share``): every
+published width, with only the keys the config lists cut to what one chip
+of the stated deployment holds. The run executes on the host's devices.
 
 Engine selection (--engine): "auto" (default) runs the sparse shard_map +
 ppermute engine when the host has exactly --nodes devices and the topology
@@ -87,6 +89,8 @@ from repro.core import (DFLConfig, HostPrefetcher, MetricsBuffer,
                         stack_round_batches, fully_connected,
                         paper_quasi_ring)
 from repro.core.compression import Identity, tree_wire_bits
+from repro.core.substrate import make_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.lm import (SyntheticLM, lm_batches_for_cohort,
                            lm_batches_for_dfl)
 from repro.faults import CohortSampler, FaultPlan, load_fault_spec
@@ -118,7 +122,11 @@ def make_optimizer(name: str, lr: float):
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the arch's smoke-test variant; "
+                         "--no-reduced trains its chip share at the "
+                         "published widths")
     ap.add_argument("--nodes", type=int, default=4)
     ap.add_argument("--tau1", type=int, default=4)
     ap.add_argument("--tau2", type=int, default=4)
@@ -212,7 +220,14 @@ def main(argv=None) -> None:
     tel = Telemetry(path=args.telemetry_out or None, meta=dict(vars(args)))
 
     arch = get_arch(args.arch)
-    cfg = arch.reduced
+    if args.reduced:
+        cfg = arch.reduced
+    elif arch.chip_share is None:
+        raise SystemExit(f"--no-reduced: {arch.arch_id} has no chip-share "
+                         "config (ArchConfig.chip_share)")
+    else:
+        cfg = arch.chip_share.model
+    enable_compile_cache()
     n = args.nodes
     population = args.virtual_nodes
     sampler = None
@@ -268,19 +283,12 @@ def main(argv=None) -> None:
     def loss_fn(p, b, k):
         return train_loss(p, b, cfg, k)
 
-    params0, _ = init_params(cfg, jax.random.key(0))
-    state = init_state(params0, population or n, opt, jax.random.key(1),
-                       compressed=comp is not None)
-    start_round = 0
-    if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
-        restored, start_round = restore_checkpoint(args.ckpt_dir, state.params)
-        state = state._replace(
-            params=jax.tree_util.tree_map(jnp.asarray, restored))
-        print(f"restored round {start_round} from {args.ckpt_dir}")
+    # shapes only: the wire accounting and planner prior need no values.
+    params0, _ = init_params(cfg, jax.random.key(0), abstract=True)
 
     mesh = None
     if not population and args.engine != "dense" and len(jax.devices()) == n:
-        mesh = jax.make_mesh((n,), ("nodes",))
+        mesh = make_mesh((n,), ("nodes",))
 
     schedule_mode = args.schedule
     if schedule_mode == "auto":
@@ -343,16 +351,29 @@ def main(argv=None) -> None:
             "sparse engine needs #devices == --nodes and a circulant "
             f"topology (devices={len(jax.devices())}, nodes={n}, "
             f"topology={topology.name})")
-    if population:
-        engine = "batched"
-    else:
-        engine = "sparse" if (args.engine != "dense" and eligible) else "dense"
     executor = RoundExecutor(
-        dcfg_max, loss_fn, opt, engine=engine, mesh=mesh,
+        dcfg_max, loss_fn, opt, engine=args.engine, mesh=mesh,
         node_axes=("nodes",), use_kernels=args.use_kernels,
         dynamic=args.dispatch == "fused",
         participation=fault_plan is not None, telemetry=tel,
         overlap=args.overlap, population=population or None)
+    engine = executor.engine
+
+    def build_state():
+        params, _ = init_params(cfg, jax.random.key(0))
+        return init_state(params, population or n, opt, jax.random.key(1),
+                          compressed=comp is not None)
+
+    # one program builds the stacked state in the engine's layout, so a
+    # model that fills the device is never held twice (a single-model
+    # copy beside the stack, or the stack on one device of a ring).
+    state = jax.jit(build_state, out_shardings=executor.state_sharding)()
+    start_round = 0
+    if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
+        restored, start_round = restore_checkpoint(args.ckpt_dir, state.params)
+        state = executor.place(state._replace(
+            params=jax.tree_util.tree_map(jnp.asarray, restored)))
+        print(f"restored round {start_round} from {args.ckpt_dir}")
 
     # Wire accounting is DEPLOYMENT cost (what a real DFL network ships:
     # engine="auto" = per-neighbor when circulant), not the host-simulation
@@ -463,14 +484,9 @@ def main(argv=None) -> None:
         if controller is not None:
             controller.spend_overhead(time.perf_counter() - tw0)
 
-    profiling = False
     if args.profile_dir:
-        try:
-            jax.profiler.start_trace(args.profile_dir)
-            profiling = True
-            print(f"jax profiler trace -> {args.profile_dir}")
-        except Exception as e:  # profiler backends vary; never fatal
-            print(f"profiler unavailable ({e}); continuing without")
+        jax.profiler.start_trace(args.profile_dir)
+        print(f"jax profiler trace -> {args.profile_dir}")
 
     if args.rounds > 0:
         warm_executables(remaining_chunk_lens(start_round, 0), tau1, tau2)
@@ -742,11 +758,8 @@ def main(argv=None) -> None:
         prefetch.close()
     if args.ckpt_dir:
         do_checkpoint(start_round + rounds_done, {})
-    if profiling:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            print(f"profiler stop failed ({e})")
+    if args.profile_dir:
+        jax.profiler.stop_trace()
     # run-level summary counters: the stream-derived history view reads
     # schedule_mode / compile counts from here, and reports read the
     # final wire/prefetch totals. compile_count must equal
@@ -756,6 +769,9 @@ def main(argv=None) -> None:
              schedule_mode=schedule_mode,
              rounds_done=rounds_done,
              engine=engine,
+             state_devices=len(
+                 jax.tree_util.tree_leaves(state.params)[0].sharding
+                 .device_set),
              compile_count_warmup=compiles_after_warmup,
              compile_count=executor.compile_count,
              wire_bits_total=wire_total,

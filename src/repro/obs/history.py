@@ -73,12 +73,15 @@ def history_view(events: Iterable[dict]) -> dict:
                            zip(history["tau1"], history["tau2"])]
 
     # Run-level summary counters (train.py emits one "run-summary"
-    # counters event at the end; last writer wins).
+    # counters event at the end; last writer wins): the engine that ran
+    # and how many devices its final state spans, besides the schedule
+    # and compile counts.
     for ev in events:
         if ev.get("type") != "counters":
             continue
         d = ev.get("data", {})
-        for key in ("schedule_mode", "compile_count_warmup", "compile_count"):
+        for key in ("schedule_mode", "engine", "state_devices",
+                    "compile_count_warmup", "compile_count"):
             if key in d:
                 history[key] = d[key]
     return history

@@ -109,6 +109,30 @@ def test_full_config_matches_assignment(arch_id):
     assert m.citation, f"{arch_id} missing source citation"
 
 
+@pytest.mark.parametrize(
+    "arch_id", [a for a in ARCH_IDS if REGISTRY[a].chip_share is not None])
+def test_chip_share_keeps_published_widths(arch_id):
+    """A chip share differs from the published config only in the keys it
+    lists under ``reduced`` (and its name), and states its deployment."""
+    import dataclasses
+
+    arch = REGISTRY[arch_id]
+    share = arch.chip_share
+    changed = {f.name for f in dataclasses.fields(arch.model)
+               if getattr(share.model, f.name) != getattr(arch.model, f.name)}
+    assert changed - {"name"} == set(share.reduced)
+    assert share.source and share.deployment
+    assert share.model.num_layers % share.model.period == 0
+
+
+def test_train_no_reduced_needs_a_chip_share():
+    from repro.launch import train
+
+    arch_id = next(a for a in ARCH_IDS if REGISTRY[a].chip_share is None)
+    with pytest.raises(SystemExit, match="no chip-share config"):
+        train.main(["--arch", arch_id, "--no-reduced", "--rounds", "1"])
+
+
 def test_gemma3_pattern_is_5_local_1_global():
     m = REGISTRY["gemma3-4b"].model
     globals_ = [i for i, s in enumerate(m.layer_specs()) if s.window == 0]

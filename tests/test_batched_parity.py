@@ -205,11 +205,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
+from repro.core.substrate import make_mesh
 from repro.core import DFLConfig, RoundExecutor, init_state, ring
 from repro.optim import sgd
 
 N, DIM, TAU1, TAU2, K = 8, 7, 2, 1, 3
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 topo = ring(N)
 opt = sgd(0.1)
 

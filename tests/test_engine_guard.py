@@ -19,7 +19,7 @@ import subprocess
 import sys
 
 import pytest
-from jax.sharding import AbstractMesh
+from jax.sharding import AbstractMesh, AxisType
 
 from repro.core import DFLConfig, make_round_fn, ring
 from repro.core.dfl import sparse_engine_eligible
@@ -34,7 +34,9 @@ def _loss(p, b, k=None):
 
 
 def _mesh(*axes):
-    return AbstractMesh(tuple(axes))
+    names, sizes = zip(*axes)
+    return AbstractMesh(sizes, names,
+                        axis_types=(AxisType.Auto,) * len(names))
 
 
 def test_select_engine_routes_partial_auto_mesh_dense():
@@ -86,6 +88,7 @@ GUARD_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
+from repro.core.substrate import make_mesh
 from repro.core import DFLConfig, make_round_fn, ring
 from repro.core.sharded import make_sharded_round_fn
 from repro.launch.steps import select_engine
@@ -94,7 +97,7 @@ from repro.optim import sgd
 def loss(p, b, k=None):
     return jnp.mean((p["w"][None] - b) ** 2)
 
-mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+mesh42 = make_mesh((4, 2), ("data", "model"))
 cfg = DFLConfig(tau1=2, tau2=1, topology=ring(4))
 
 # half 1: the sparse builder raises loudly on constrain + >1 auto axis,
@@ -121,7 +124,7 @@ print("GUARD_NO_CONSTRAIN_OK")
 # half 2: auto-selection on the CONCRETE mesh routes dense, so the
 # production path (which always passes a constrain) never hits the raise.
 assert select_engine("auto", cfg, mesh42, "gossip-dp") == "dense"
-mesh8 = jax.make_mesh((8,), ("data",))
+mesh8 = make_mesh((8,), ("data",))
 cfg8 = DFLConfig(tau1=2, tau2=1, topology=ring(8))
 assert select_engine("auto", cfg8, mesh8, "gossip-dp") == "sparse"
 print("GUARD_ROUTES_DENSE_OK")

@@ -17,6 +17,7 @@ from repro.core import (DFLConfig, DenseSubstrate, consensus_distance,
                         torus)
 from repro.core import mixing as M
 from repro.core import substrate as sub_lib
+from repro.core.substrate import make_mesh
 from repro.core.compression import Identity, tree_wire_bits
 
 
@@ -103,7 +104,7 @@ def test_sharded_round_fn_rejects_mismatched_mesh():
     from repro.core import init_state, make_round_fn
     from repro.optim import sgd
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = DFLConfig(tau1=1, tau2=1, topology=ring(4))
     with pytest.raises(AssertionError, match="4 nodes"):
         make_round_fn(cfg, lambda p, b, k: 0.0, sgd(0.1),
@@ -133,12 +134,12 @@ def test_sparse_engine_eligibility_rules():
 
 
 # ---------------------------------------------------------------------------
-# Version-compat shims (must work on the pinned 0.4.37 AND newer JAX)
+# substrate shard_map / axis_size wrappers
 # ---------------------------------------------------------------------------
 
 
 def test_compat_shard_map_and_axis_size_single_device():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     def body(x):
         return x * sub_lib.axis_size("data") + jax.lax.axis_index("data")
@@ -149,7 +150,7 @@ def test_compat_shard_map_and_axis_size_single_device():
 
 
 def test_mix_ppermute_empty_shifts_is_identity():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = {"w": jnp.arange(4.0)[None]}
     out = sub_lib.shard_map(
         lambda p: M.mix_ppermute_shifts(p, [], 1.0, "data"),

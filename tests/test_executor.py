@@ -442,8 +442,8 @@ def test_metrics_buffer_uses_metric_carried_taus():
 
 
 def test_executor_warmup_precompiles_without_stats():
-    """warmup() pays the compile for a batch shape on a throwaway state
-    copy: the first real dispatch at that shape then adds no compile, and
+    """warmup() pays the compile for a batch shape ahead of time, from
+    shapes: the first real dispatch at that shape then adds no compile, and
     warmup leaves dispatch statistics and the caller's state untouched."""
     opt = sgd(0.1)
     ex = RoundExecutor(DFLConfig(tau1=3, tau2=2, topology=ring(N)),
@@ -467,11 +467,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
+from repro.core.substrate import make_mesh
 from repro.core import (DFLConfig, RoundExecutor, init_state, make_compressor,
                         make_round_fn, ring, stack_round_batches)
 from repro.optim import sgd
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 N = 8
 topo = ring(N)
 opt = sgd(0.1)
@@ -549,7 +550,7 @@ print("SPARSE_TRAJECTORY_OK", err3)
 # constrain guard: a >1-sized auto axis + constrain must raise loudly
 # (the re-assertion would be silently dropped); a node-only mesh accepts
 # and ignores it.
-mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+mesh42 = make_mesh((4, 2), ("data", "model"))
 cfg4 = DFLConfig(tau1=2, tau2=1, topology=ring(4))
 try:
     make_round_fn(cfg4, noisy_loss, sgd(0.1), constrain=lambda t: t,

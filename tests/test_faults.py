@@ -493,12 +493,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
+from repro.core.substrate import make_mesh
 from repro.core import (DFLConfig, RoundExecutor, init_state,
                         make_compressor, ring, stack_round_batches)
 from repro.faults import FaultPlan, NodeCrash, LinkOutage
 from repro.optim import sgd
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 N = 8
 topo = ring(N)
 opt = sgd(0.1)

@@ -335,7 +335,9 @@ def test_backend_detection_is_lazy(monkeypatch):
         assert registry.resolve_mode("qsgd_quantize", None) == "interpret"
         # explicit interpret always wins
         assert registry.resolve_mode("qsgd_quantize", True) == "interpret"
-        assert registry.resolve_mode("topk_partials", False) == "mosaic"
+        assert registry.resolve_mode("qsgd_quantize", False) == "mosaic"
+        # ...except that an op Mosaic can't lower is never forced into it
+        assert registry.resolve_mode("topk_partials", False) == "fallback"
     finally:
         registry.reset_backend_cache()
 

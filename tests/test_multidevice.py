@@ -21,12 +21,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
+from repro.core.substrate import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import ring, mixing, DFLConfig, init_state, make_round_fn
 from repro.optim import sgd
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 N = 8
 topo = ring(N)
 x = jax.random.normal(jax.random.key(0), (N, 4, 33))
@@ -163,3 +164,48 @@ def test_multidevice_semantics():
     assert "CDFL_PARITY_OK" in out.stdout
     assert "KERNELS_OK" in out.stdout
     assert "TOPK_KERNELS_OK" in out.stdout
+
+
+TRAIN_SCRIPT = r"""
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+from repro.core.sharded import ENGINE_PARITY_LOSS_ATOL
+from repro.launch import train
+
+out = sys.argv[1]
+common = ["--arch", "qwen3-1.7b", "--nodes", "4", "--topology", "ring",
+          "--tau1", "2", "--tau2", "2", "--superstep", "2", "--rounds", "4",
+          "--batch", "1", "--seq", "32", "--log-every", "10"]
+hist = {}
+for engine in ("sparse", "dense"):
+    path = os.path.join(out, engine + ".json")
+    train.main(common + ["--engine", engine, "--history-out", path])
+    with open(path) as f:
+        hist[engine] = json.load(f)
+s, d = hist["sparse"], hist["dense"]
+assert s["engine"] == "sparse" and d["engine"] == "dense"
+# the ring's state is split over all 4 devices, and it is built there:
+# the first dispatch adds no compile beyond the ahead-of-time warmup.
+assert s["state_devices"] == 4, s["state_devices"]
+assert s["compile_count"] == s["compile_count_warmup"] == 1, s
+# the parameters are bf16: the losses drift apart after the first gossip
+# (see ENGINE_PARITY_LOSS_ATOL).
+diff = max(abs(a - b) for a, b in zip(s["loss"], d["loss"]))
+assert s["loss"][0] == d["loss"][0], (s["loss"], d["loss"])
+assert diff < ENGINE_PARITY_LOSS_ATOL, diff
+print("TRAIN_SPARSE_OK", diff)
+"""
+
+
+@pytest.mark.slow
+def test_train_cli_sparse_ring_matches_dense(tmp_path):
+    """``train.main`` on the sparse engine (one node per device): state on
+    every device, zero compiles after warmup, and the dense engine's
+    losses (the first round's exactly)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", TRAIN_SCRIPT, str(tmp_path)],
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "TRAIN_SPARSE_OK" in out.stdout

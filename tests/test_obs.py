@@ -184,7 +184,8 @@ def _sample_events():
     tel.emit("counters", track="dispatch", name="superstep-counters",
              compile_count=1, kernel_pallas_calls=3)
     tel.emit("counters", track="run", name="run-summary",
-             schedule_mode="fixed", compile_count_warmup=1,
+             schedule_mode="fixed", engine="dense", state_devices=1,
+             compile_count_warmup=1,
              compile_count=1, kernel_pallas_calls=2)
     return tel.events
 
@@ -235,6 +236,7 @@ def test_history_view_reproduces_legacy_contract():
     assert h["plan_events"][0]["cause"] == "initial"
     assert h["schedule_mode"] == "fixed"
     assert h["compile_count"] == 1 and h["compile_count_warmup"] == 1
+    assert h["engine"] == "dense" and h["state_devices"] == 1
 
 
 # ---------------------------------------------------------------------------

@@ -545,12 +545,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
+from repro.core.substrate import make_mesh
 from repro.core import (DFLConfig, RoundExecutor, init_state, make_compressor,
                         ring, stack_round_batches)
 from repro.optim import sgd
 
 N, DIM = 8, 17
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 opt = sgd(0.05)
 
 def noisy_loss(p, b, k=None):
