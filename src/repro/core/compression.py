@@ -109,7 +109,8 @@ class TopK(Compressor):
             return kernel_ops.top_k_compress(x, k)
         # threshold = k-th largest magnitude; ties keep >= threshold (may keep
         # a few extra ties — still satisfies Assumption 2).
-        thresh = jax.lax.top_k(jnp.abs(flat), k)[0][-1]
+        with jax.named_scope("compress.threshold"):
+            thresh = jax.lax.top_k(jnp.abs(flat), k)[0][-1]
         kept = jnp.where(jnp.abs(flat) >= thresh, flat, 0.0)
         return kept.reshape(x.shape).astype(x.dtype)
 

@@ -231,10 +231,12 @@ def _local_updates(
         keys = sub.node_keys(jax.random.fold_in(local_key, t))
         losses, grads = sub.vmap(grad_one)(params, batch_t, keys)
         grads = constrain(grads)
-        updates, opt_state = sub.vmap(opt.update)(grads, opt_state, params)
-        params = jax.tree_util.tree_map(
-            lambda p, u: (p + u).astype(p.dtype), params, updates)
-        params = constrain(params)
+        with jax.named_scope("dfl.local.update"):
+            updates, opt_state = sub.vmap(opt.update)(grads, opt_state,
+                                                      params)
+            params = jax.tree_util.tree_map(
+                lambda p, u: (p + u).astype(p.dtype), params, updates)
+            params = constrain(params)
         return (params, opt_state), losses
 
     def finish(params, opt_state, per_node_loss):
@@ -376,9 +378,10 @@ def local_phase(
     mean_loss).
     """
     constrain = constrain or (lambda t: t)
-    return _local_updates(cfg, loss_fn, opt, sub, params, opt_state,
-                          local_key, batches, constrain, tau1=tau1,
-                          node_mask=node_mask)
+    with jax.named_scope("dfl.local"):
+        return _local_updates(cfg, loss_fn, opt, sub, params, opt_state,
+                              local_key, batches, constrain, tau1=tau1,
+                              node_mask=node_mask)
 
 
 def gossip_phase(
@@ -394,14 +397,16 @@ def gossip_phase(
     selection and the comm-key derivation agree on that index).
     """
     constrain = constrain or (lambda t: t)
-    if cfg.is_compressed:
-        assert hat is not None, "C-DFL needs init_state(..., compressed=True)"
-        params, hat = _communicate_choco(cfg, params, hat, comm_key, sub,
-                                         tau2=tau2, edge_mask=edge_mask)
-    else:
-        params = _communicate_plain(cfg, sub, params, round_idx, tau2=tau2,
-                                    edge_mask=edge_mask)
-        params = constrain(params)
+    with jax.named_scope("dfl.gossip"):
+        if cfg.is_compressed:
+            assert hat is not None, (
+                "C-DFL needs init_state(..., compressed=True)")
+            params, hat = _communicate_choco(cfg, params, hat, comm_key, sub,
+                                             tau2=tau2, edge_mask=edge_mask)
+        else:
+            params = _communicate_plain(cfg, sub, params, round_idx,
+                                        tau2=tau2, edge_mask=edge_mask)
+            params = constrain(params)
     return params, hat
 
 

@@ -39,6 +39,13 @@ schedule changes and round dispatch near-zero-cost:
   round-path HLO and ZERO host syncs (the ``telemetry-neutrality`` audit
   in ``repro.analysis`` proves the instrumented lowering is
   fingerprint-identical to the bare one).
+* **Profiler spans** — with or without a sink, the same points open a
+  ``jax.profiler.TraceAnnotation`` (``dfl.dispatch``, ``dfl.compile``,
+  ``dfl.prefetch.build``, ``dfl.prefetch.take``, ``dfl.metrics.flush``),
+  so a profiler trace shows what the host was doing on the device
+  trace's clock; inside the superstep the engine's ``jax.named_scope``
+  phases (``dfl.local``, ``dfl.local.update``, ``dfl.gossip``) name the
+  device ops.
 
 A keyed compile cache (``dynamic=False``) remains as the static fallback for
 configs the dynamic path can't express (``mixing_impl='dense_power'``).
@@ -595,17 +602,19 @@ class RoundExecutor:
 
     def _dispatch_prepared(self, state: DFLState, batches: PyTree,
                            arr: np.ndarray, dev, k: int):
-        state = self.place(state)
-        self.dispatch_count += 1
-        self.rounds_dispatched += k
-        if self._tel is None:
-            return self._run_trajectory(state, batches, arr, dev, k)
-        t0 = self._tel.now()
-        out = self._run_trajectory(state, batches, arr, dev, k)
-        # On sync backends (this jaxlib's CPU client) the superstep
-        # EXECUTES inside the call, so dur is real device time; on async
-        # backends it is enqueue cost and the flush event carries the rest.
-        dur = self._tel.now() - t0
+        with jax.profiler.TraceAnnotation("dfl.dispatch"):
+            state = self.place(state)
+            self.dispatch_count += 1
+            self.rounds_dispatched += k
+            if self._tel is None:
+                return self._run_trajectory(state, batches, arr, dev, k)
+            t0 = self._tel.now()
+            out = self._run_trajectory(state, batches, arr, dev, k)
+            # On sync backends (this jaxlib's CPU client) the superstep
+            # EXECUTES inside the call, so dur is real device time; on
+            # async backends (the TPU) it is enqueue cost and the flush
+            # event carries the rest.
+            dur = self._tel.now() - t0
         self._tel.emit("superstep", track="dispatch", name=f"superstep-k{k}",
                        t=t0, dur=dur, k=k, dispatch=self.dispatch_count)
         if self.overlap == "pipeline":
@@ -698,8 +707,9 @@ class RoundExecutor:
             fn = self._static_fn(tau1, tau2)
             args = (jax.tree_util.tree_map(lambda b: b[:, :tau1], batches),)
         args = jax.tree_util.tree_map(spec, (self.place(state),) + args)
-        with (self._tel.span("warmup", track="dispatch")
-              if self._tel is not None else contextlib.nullcontext()):
+        with jax.profiler.TraceAnnotation("dfl.compile"), (
+                self._tel.span("warmup", track="dispatch")
+                if self._tel is not None else contextlib.nullcontext()):
             fn.lower(*args).compile()
 
 
@@ -759,24 +769,8 @@ class HostPrefetcher:
         def work():
             t0 = tel.now() if tel is not None else 0.0
             try:
-                for attempt in range(self._retries + 1):
-                    try:
-                        box["out"] = fn(*args)
-                        box.pop("err", None)
-                        return
-                    except BaseException as e:  # re-raised on take()
-                        box["err"] = e
-                        if (attempt >= self._retries
-                                or not isinstance(e, Exception)):
-                            return
-                        self.stats["retries"] += 1
-                        if tel is not None:
-                            tel.emit("prefetch", track="prefetch",
-                                     name="retry", action="retry",
-                                     attempt=attempt + 1)
-                        # interruptible backoff: close() wakes it
-                        if self._stop.wait(self._backoff_s * (2 ** attempt)):
-                            return
+                with jax.profiler.TraceAnnotation("dfl.prefetch.build"):
+                    self._build(fn, args, box)
             finally:
                 if tel is not None:
                     tel.emit("prefetch", track="prefetch", name="build",
@@ -787,6 +781,27 @@ class HostPrefetcher:
         t.start()
         self._pending = (t, box, meta)
 
+    def _build(self, fn: Callable, args: tuple, box: dict) -> None:
+        """The worker's build with its retries: ``box`` gets ``out`` or
+        the last ``err``."""
+        for attempt in range(self._retries + 1):
+            try:
+                box["out"] = fn(*args)
+                box.pop("err", None)
+                return
+            except BaseException as e:  # re-raised on take()
+                box["err"] = e
+                if attempt >= self._retries or not isinstance(e, Exception):
+                    return
+                self.stats["retries"] += 1
+                if self._tel is not None:
+                    self._tel.emit("prefetch", track="prefetch",
+                                   name="retry", action="retry",
+                                   attempt=attempt + 1)
+                # interruptible backoff: close() wakes it
+                if self._stop.wait(self._backoff_s * (2 ** attempt)):
+                    return
+
     @property
     def pending_meta(self) -> Any:
         return self._pending[2] if self._pending is not None else None
@@ -796,7 +811,8 @@ class HostPrefetcher:
             raise RuntimeError("nothing scheduled — call schedule() first")
         t, box, meta = self._pending
         self._pending = None
-        t.join()
+        with jax.profiler.TraceAnnotation("dfl.prefetch.take"):
+            t.join()
         if "err" in box:
             self.stats["errors"] += 1
             raise box["err"]
@@ -899,7 +915,8 @@ class MetricsBuffer:
         if not self._pending:
             return []
         block0 = time.perf_counter()
-        jax.block_until_ready([m for *_, m in self._pending])
+        with jax.profiler.TraceAnnotation("dfl.metrics.flush"):
+            jax.block_until_ready([m for *_, m in self._pending])
         now = time.perf_counter()
         elapsed = now - (self._window_start or now)
         n = self.pending_rounds

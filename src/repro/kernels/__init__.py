@@ -32,7 +32,6 @@ from repro.kernels.ops import (
     op_stats,
     op_stats_delta,
     qsgd_quantize,
-    reset_op_stats,
     top_k_compress,
     topk_threshold,
 )
@@ -67,5 +66,4 @@ __all__ = [
     "choco_topk_move",
     "op_stats",
     "op_stats_delta",
-    "reset_op_stats",
 ]

@@ -30,8 +30,11 @@ tick when wrapper bodies execute, so count over ``eager_impl`` calls
 (un-jitted, deterministic per call) — see ``benchmarks/bench_kernels``.
 Scope a measurement with ``with op_stats_delta() as d:`` — snapshot
 arithmetic, no global reset, so concurrent/nested measurement scopes
-can't clobber each other (``reset_op_stats()`` is deprecated for exactly
-that race).
+can't clobber each other.
+
+The TopK threshold select runs under ``jax.named_scope(
+"compress.threshold")``, so a profiler trace attributes its device time
+(the sort behind ``lax.top_k``) by the HLO ``op_name`` metadata.
 """
 from __future__ import annotations
 
@@ -122,19 +125,6 @@ def op_stats_delta() -> Iterator[OpStatsDelta]:
         yield d
     finally:
         d._close({k: _STATS[k] - before.get(k, 0) for k in _STATS})
-
-
-def reset_op_stats() -> None:
-    """Deprecated: zeroes the GLOBAL counters, which races every other
-    measurement scope in the process (two bench sections resetting under
-    each other read garbage). Use ``op_stats_delta()``."""
-    warnings.warn(
-        "repro.kernels.ops.reset_op_stats() is deprecated: a global reset "
-        "races across concurrent measurement scopes — use "
-        "`with op_stats_delta() as d:` snapshot/delta attribution instead.",
-        DeprecationWarning, stacklevel=2)
-    for k in _STATS:
-        _STATS[k] = 0
 
 
 def __getattr__(name: str):
@@ -250,14 +240,15 @@ def _threshold_impl(x: jnp.ndarray, k: int, mode: str) -> jnp.ndarray:
     if not 1 <= k <= flat.size:
         raise ValueError(
             f"TopK k={k} out of range for a size-{flat.size} vector")
-    if mode == "fallback":
-        return jax.lax.top_k(jnp.abs(flat), k)[0][k - 1]
-    x2d, _ = _to_2d(x)
-    cand = min(k, _TILE)
-    _STATS["pallas_calls"] += 1
-    parts = _topk.topk_partials_2d(x2d, cand=cand,
-                                   interpret=(mode == "interpret"))
-    return jax.lax.top_k(parts.reshape(-1), k)[0][k - 1]
+    with jax.named_scope("compress.threshold"):
+        if mode == "fallback":
+            return jax.lax.top_k(jnp.abs(flat), k)[0][k - 1]
+        x2d, _ = _to_2d(x)
+        cand = min(k, _TILE)
+        _STATS["pallas_calls"] += 1
+        parts = _topk.topk_partials_2d(x2d, cand=cand,
+                                       interpret=(mode == "interpret"))
+        return jax.lax.top_k(parts.reshape(-1), k)[0][k - 1]
 
 
 def _topk_threshold_impl(x, *, k: int, mode: str):
@@ -361,14 +352,15 @@ def _choco_topk_move_impl(x, y, mixed_y, gamma, *, k: int, tmode: str,
     # candidate select and the mask input of the fused kernel, so the
     # threshold and the kept-set decisions read the identical tensor.
     d2d, n = _to_2d(diff)
-    if tmode == "fallback":
-        thresh = jax.lax.top_k(jnp.abs(diff), k)[0][k - 1]
-    else:
-        cand = min(k, _TILE)
-        _STATS["pallas_calls"] += 1
-        parts = _topk.topk_partials_2d(d2d, cand=cand,
-                                       interpret=(tmode == "interpret"))
-        thresh = jax.lax.top_k(parts.reshape(-1), k)[0][k - 1]
+    with jax.named_scope("compress.threshold"):
+        if tmode == "fallback":
+            thresh = jax.lax.top_k(jnp.abs(diff), k)[0][k - 1]
+        else:
+            cand = min(k, _TILE)
+            _STATS["pallas_calls"] += 1
+            parts = _topk.topk_partials_2d(d2d, cand=cand,
+                                           interpret=(tmode == "interpret"))
+            thresh = jax.lax.top_k(parts.reshape(-1), k)[0][k - 1]
     x2d, _ = _to_2d(x)
     y2d, _ = _to_2d(y)
     my2d, _ = _to_2d(mixed_y)

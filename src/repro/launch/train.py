@@ -56,14 +56,15 @@ CLI taus.
 Telemetry (--telemetry-out events.jsonl): every run streams typed events
 through ``repro.obs.Telemetry`` — rounds, supersteps, plan/replan/probe
 decisions, compiles, prefetch builds, metric flushes, checkpoints, and
-per-superstep counter snapshots (kernel op_stats deltas, compile count,
-wire-bit totals, prefetch hit/stale). The --history-out JSON is a
-schema-versioned VIEW over that stream (``repro.obs.history_view``) with
+per-superstep counter snapshots (compile count, wire-bit totals,
+prefetch hit/stale). The --history-out JSON is a schema-versioned VIEW
+over that stream (``repro.obs.history_view``) with
 the same fields as before plus ``schema_version``. Inspect a stream with
 ``python -m repro.obs report|validate|trace export``. --profile-dir DIR
 additionally wraps the run in ``jax.profiler`` so XLA device activity
-can be lined up under the same timeline. All telemetry is host-side:
-zero syncs, zero recompiles on the round path (audited —
+can be lined up under the same timeline; the executor's ``dfl.*`` host
+spans and the engine's named phases appear in that trace. All telemetry
+is host-side: zero syncs, zero recompiles on the round path (audited —
 ``telemetry-neutrality`` in ``repro.analysis``).
 
 All durations here are measured on the monotonic ``time.perf_counter``
@@ -94,7 +95,6 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.data.lm import (SyntheticLM, lm_batches_for_cohort,
                            lm_batches_for_dfl)
 from repro.faults import CohortSampler, FaultPlan, load_fault_spec
-from repro.kernels.ops import op_stats_delta
 from repro.launch.steps import kernelize_compressor
 from repro.models import train_loss, init_params
 from repro.obs import Telemetry, history_view
@@ -503,17 +503,15 @@ def main(argv=None) -> None:
     last_ckpt = start_round
     last_loss = float("nan")
 
-    def emit_counters(round0: int, kk: int, opd) -> None:
-        """Per-superstep counter attribution: kernel op_stats deltas from
-        the enclosing dispatch, cumulative compile/wire/prefetch state."""
+    def emit_counters(round0: int, kk: int) -> None:
+        """Per-superstep counters: cumulative compile/wire/prefetch state."""
         tel.emit("counters", track="dispatch", name="superstep-counters",
                  round0=round0, k=kk,
                  compile_count=executor.compile_count,
                  wire_bits_total=wire_total,
                  prefetch_taken=prefetch.stats["taken"],
                  prefetch_stale=prefetch.stats["stale"],
-                 prefetch_cancelled=prefetch.stats["cancelled"],
-                 **{f"kernel_{key}": v for key, v in opd.as_dict().items()})
+                 prefetch_cancelled=prefetch.stats["cancelled"])
 
     def do_checkpoint(step: int, extra: dict) -> None:
         ck0 = tel.now()
@@ -657,9 +655,8 @@ def main(argv=None) -> None:
                     sched_rows = sampler.cohort_trajectory(
                         sched_rows, r, num_edges=topology.num_edges)
                 t_dispatch = time.perf_counter()
-                with op_stats_delta() as opd:
-                    state, metrics = executor.dispatch_trajectory(
-                        state, batches, sched_rows)
+                state, metrics = executor.dispatch_trajectory(
+                    state, batches, sched_rows)
                 buffer.push(r, len(taus), None, None, metrics,
                             dispatched_at=t_dispatch)
                 r += len(taus)
@@ -670,7 +667,7 @@ def main(argv=None) -> None:
                 # next_trajectory call will see, so the prediction is
                 # deterministic-identical barring later overhead spend.
                 pending = schedule_predicted(r, rounds_done)
-                emit_counters(r - len(taus), len(taus), opd)
+                emit_counters(r - len(taus), len(taus))
                 if (args.ckpt_every and args.ckpt_dir
                         and r // args.ckpt_every
                         > last_ckpt // args.ckpt_every):
@@ -689,26 +686,25 @@ def main(argv=None) -> None:
                 prefetch.mark_stale()
                 with tel.span("stale-rebuild", track="prefetch"):
                     batches = build_batches(r, k, tau1)
-            t_dispatch = time.perf_counter()  # sync backends EXECUTE inside
-            with op_stats_delta() as opd:     # dispatch
-                if fault_plan is not None or sampler is not None:
-                    # widen the uniform chunk to masked participation /
-                    # sampled cohort rows — same executable, the masks and
-                    # cohort ids are just more xs columns.
-                    rows = np.tile(np.array([[tau1, tau2]], np.int32),
-                                   (k, 1))
-                    if fault_plan is not None:
-                        rows = fault_plan.mask_trajectory(rows, r)
-                    if sampler is not None:
-                        rows = sampler.cohort_trajectory(
-                            rows, r, num_edges=topology.num_edges)
-                    state, metrics = executor.dispatch_trajectory(
-                        state, batches, rows)
-                else:
-                    state, metrics = executor.dispatch(state, batches, tau1,
-                                                       tau2)
+            # sync backends EXECUTE inside dispatch
+            t_dispatch = time.perf_counter()
+            if fault_plan is not None or sampler is not None:
+                # widen the uniform chunk to masked participation /
+                # sampled cohort rows — same executable, the masks and
+                # cohort ids are just more xs columns.
+                rows = np.tile(np.array([[tau1, tau2]], np.int32), (k, 1))
+                if fault_plan is not None:
+                    rows = fault_plan.mask_trajectory(rows, r)
+                if sampler is not None:
+                    rows = sampler.cohort_trajectory(
+                        rows, r, num_edges=topology.num_edges)
+                state, metrics = executor.dispatch_trajectory(
+                    state, batches, rows)
+            else:
+                state, metrics = executor.dispatch(state, batches, tau1,
+                                                   tau2)
             buffer.push(r, k, tau1, tau2, metrics, dispatched_at=t_dispatch)
-            emit_counters(r, k, opd)
+            emit_counters(r, k)
             r += k
             rounds_done += k
             # overlap: build the NEXT superstep's batches while the device runs

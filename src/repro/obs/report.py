@@ -98,21 +98,14 @@ def run_report(events: Iterable[dict]) -> dict:
                           for e in events
                           if e.get("type") in ("plan", "replan", "probe"))
 
-    # Counters: the final snapshot wins for cumulative values; kernel_*
-    # keys are per-superstep deltas so they sum.
+    # Counters are cumulative snapshots: the final one wins.
     counters: Dict[str, float] = {}
-    kernel_totals: Dict[str, float] = {}
     for ev in events:
         if ev.get("type") != "counters":
             continue
         for k, v in (ev.get("data") or {}).items():
-            if not isinstance(v, (int, float)) or isinstance(v, bool):
-                continue
-            if k.startswith("kernel_"):
-                kernel_totals[k] = kernel_totals.get(k, 0) + v
-            else:
+            if isinstance(v, (int, float)) and not isinstance(v, bool):
                 counters[k] = v
-    counters.update(kernel_totals)
 
     compiles = [e["data"]["count"] for e in events
                 if e.get("type") == "compile"

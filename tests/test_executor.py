@@ -458,6 +458,82 @@ def test_executor_warmup_precompiles_without_stats():
     assert int(out.round_idx) == 2
 
 
+@pytest.mark.parametrize("comp", [None, "top_k", "top_k_kernels"])
+def test_superstep_names_its_phases_in_op_metadata(comp):
+    """The engine's ``jax.named_scope`` phases reach the compiled
+    superstep's ``op_name`` metadata, where a profiler trace's reduction
+    reads them; backward is the VJP marker under ``dfl.local``."""
+    import re
+
+    compressor = (make_compressor("top_k", frac=0.25,
+                                  use_kernels=comp == "top_k_kernels")
+                  if comp else None)
+    opt = sgd(0.1)
+    def loss(p, b, k=None):
+        return jnp.mean(jnp.tanh(p["w"] * b) ** 2)
+
+    ex = RoundExecutor(DFLConfig(tau1=2, tau2=2, topology=ring(N),
+                                 compression=compressor, gamma=0.5),
+                       loss, opt)
+    st = fresh_state(opt, compressed=compressor is not None)
+    stacked = stack_round_batches([batches_for(2)] * 2, tau1_max=2)
+    text = ex.lower_superstep(st, stacked,
+                              np.zeros((2, ex.row_width), np.int32)
+                              ).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    scopes = {"dfl.local", "dfl.local.update", "dfl.gossip"}
+    if compressor is not None:
+        scopes.add("compress.threshold")
+    # a scope under vmap reads ``vmap(compress.threshold)``
+    found = {p for n in names for p in re.split(r"[/()]", n) if p in scopes}
+    assert found == scopes
+    assert any("/dfl.local/" in n and "transpose(" in n for n in names)
+    assert all("/dfl.local/" in n for n in names if "dfl.local.update" in n)
+    assert all("/dfl.gossip/" in n for n in names
+               if "compress.threshold" in n)
+
+
+def test_profiler_trace_holds_the_program_host_spans(tmp_path):
+    """Warmup, two dispatches fed by the prefetcher and a metrics flush
+    open ``dfl.*`` annotations on the profiler's clock, without a
+    telemetry sink."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    opt = sgd(0.1)
+    ex = RoundExecutor(DFLConfig(tau1=2, tau2=1, topology=ring(N)),
+                       noisy_loss, opt)
+    st = fresh_state(opt)
+    stacked = stack_round_batches([batches_for(2)] * 2, tau1_max=2)
+    pf, buf = HostPrefetcher(), MetricsBuffer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ex.warmup(st, stacked)
+        pf.schedule(lambda: stacked, meta=0)
+        for i in range(2):
+            batches, _ = pf.take()
+            st, metrics = ex.dispatch(st, batches, 2, 1)
+            buf.push(2 * i, 2, 2, 1, metrics)
+            if i == 0:
+                pf.schedule(lambda: stacked, meta=1)
+        buf.flush()
+    finally:
+        jax.profiler.stop_trace()
+        pf.close()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    names = [e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("dfl.")]
+    assert names.count("dfl.dispatch") == 2
+    assert names.count("dfl.prefetch.build") == 2
+    assert names.count("dfl.prefetch.take") == 2
+    assert names.count("dfl.compile") == 1
+    assert names.count("dfl.metrics.flush") == 1
+
+
 # ---------------------------------------------------------------------------
 # Sparse engine (shard_map + ppermute): 8 fake devices -> subprocess
 # ---------------------------------------------------------------------------

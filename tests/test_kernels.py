@@ -289,8 +289,9 @@ def test_fused_choco_fewer_buffer_passes():
 
 def test_op_stats_delta_scoping_and_reset_deprecation():
     """Snapshot/delta attribution: nested scopes each see their own
-    window, reading an open scope raises, and the old global
-    ``reset_op_stats`` warns (it races concurrent scopes)."""
+    window, reading an open scope raises, and nothing resets the global
+    counters (the old ``reset_op_stats`` raced concurrent scopes and is
+    gone)."""
     x, y, my, _noise = _fused_inputs((2, 3), jnp.float32)
     with ops.op_stats_delta() as outer:
         ops.eager_impl("choco_move")(x, y, my, 0.5, interpret=True)
@@ -301,10 +302,10 @@ def test_op_stats_delta_scoping_and_reset_deprecation():
     # choco_move pads x, y, mixed_y: 3 round-trips, 1 launch per call.
     assert inner.as_dict() == {"pad_roundtrips": 3, "pallas_calls": 1}
     assert outer.pad_roundtrips == 6 and outer.pallas_calls == 2
-    before = ops.op_stats()
-    with pytest.warns(DeprecationWarning, match="op_stats_delta"):
-        ops.reset_op_stats()
-    assert ops.op_stats() == {k: 0 for k in before}
+    snapshot = ops.op_stats()
+    snapshot["pallas_calls"] = 0      # a copy: a caller cannot clobber them
+    assert ops.op_stats()["pallas_calls"] >= 2
+    assert not hasattr(ops, "reset_op_stats")
 
 
 # ---------------------------------------------------------------------------

@@ -182,11 +182,11 @@ def _sample_events():
     tel.emit("flush", track="metrics", name="metrics-flush", dur=0.01,
              rounds=2)
     tel.emit("counters", track="dispatch", name="superstep-counters",
-             compile_count=1, kernel_pallas_calls=3)
+             compile_count=1, prefetch_taken=1)
     tel.emit("counters", track="run", name="run-summary",
              schedule_mode="fixed", engine="dense", state_devices=1,
              compile_count_warmup=1,
-             compile_count=1, kernel_pallas_calls=2)
+             compile_count=1, prefetch_taken=2)
     return tel.events
 
 
@@ -219,11 +219,11 @@ def test_run_report_aggregates_spans_counters_and_rounds():
     assert rep["rounds"]["loss_first"] == 2.0
     assert rep["rounds"]["loss_last"] == 1.5
     assert rep["plans"]["initial"] == 1
-    # kernel_* counter keys SUM across snapshots; others are last-wins.
-    assert rep["counters"]["kernel_pallas_calls"] == 5
+    # counters are cumulative snapshots: the last one wins.
+    assert rep["counters"]["prefetch_taken"] == 2
     assert rep["counters"]["compile_count"] == 1
     text = format_report(rep)
-    assert "rounds" in text and "kernel_pallas_calls" in text
+    assert "rounds" in text and "prefetch_taken" in text
 
 
 def test_history_view_reproduces_legacy_contract():
