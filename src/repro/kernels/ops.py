@@ -15,8 +15,9 @@ Entry points
   * ``qsgd_quantize``   — QSGD random quantization (norm fed as scalar).
   * ``gossip_mix``      — fused weighted neighbor accumulate.
   * ``choco_move``      — CHOCO consensus move, (x_new, diff) one pass.
-  * ``topk_threshold``  — k-th largest |x| via the two-pass candidate
-                          select (``repro.kernels.topk``).
+  * ``topk_threshold``  — k-th largest |x|: the two-pass candidate
+                          select (``repro.kernels.topk``), or on TPU the
+                          bisection select below.
   * ``top_k_compress``  — kernel-backed TopK sparsifier; bitwise-matches
                           ``repro.core.compression.TopK``.
   * ``choco_qsgd_move`` / ``choco_topk_move`` — the FUSED CHOCO
@@ -24,8 +25,9 @@ Entry points
     single kernel pass (``repro.kernels.choco_fused``) instead of the
     three separate padded round-trips the unfused composition pays.
 
-``op_stats()`` exposes pad-roundtrip / pallas-call counters so benchmarks
-and tests can ASSERT the fused paths touch the buffer fewer times; they
+``op_stats()`` exposes pad-roundtrip / pallas-call / threshold-bisection
+counters so benchmarks and tests can ASSERT the fused paths touch the
+buffer fewer times and which select a path traced; they
 tick when wrapper bodies execute, so count over ``eager_impl`` calls
 (un-jitted, deterministic per call) — see ``benchmarks/bench_kernels``.
 Scope a measurement with ``with op_stats_delta() as d:`` — snapshot
@@ -34,7 +36,15 @@ can't clobber each other.
 
 The TopK threshold select runs under ``jax.named_scope(
 "compress.threshold")``, so a profiler trace attributes its device time
-(the sort behind ``lax.top_k``) by the HLO ``op_name`` metadata.
+by the HLO ``op_name`` metadata. Where the candidate kernel cannot run
+(Mosaic does not lower its in-kernel ``lax.top_k``), the select is
+``_kth_largest_magnitude``: a bisection over the magnitude's bit pattern,
+one compare-and-count reduction per bit and no sort. With the sign bit
+cleared, a float's bits order as an unsigned integer exactly as its
+magnitudes do (``-0.0`` and ``+0.0`` both read 0, ``inf`` is the largest
+non-NaN pattern), so the largest pattern ``t`` with ``count(|x| >= t) >=
+k`` is bit for bit the value ``lax.top_k(jnp.abs(x), k)[0][k - 1]``
+returns, ties included.
 """
 from __future__ import annotations
 
@@ -60,12 +70,14 @@ assert all(m.BLOCK_ROWS * m.LANES == _TILE
            for m in (_choco, _fused, _mix, _topk)), (
     "kernel modules disagree on the (BLOCK_ROWS x LANES) tile size")
 
-_STATS: Dict[str, int] = {"pad_roundtrips": 0, "pallas_calls": 0}
+_STATS: Dict[str, int] = {"pad_roundtrips": 0, "pallas_calls": 0,
+                          "threshold_bisections": 0}
 
 
 def op_stats() -> Dict[str, int]:
     """Counters of buffer work: ``pad_roundtrips`` (flatten/pad/unpad
-    cycles through ``_to_2d``) and ``pallas_calls`` (kernel launches).
+    cycles through ``_to_2d``), ``pallas_calls`` (kernel launches) and
+    ``threshold_bisections`` (TopK thresholds selected by bisection).
     Python-side: they tick when a wrapper body EXECUTES — once per jit
     trace through the public entry points, or once per call through
     ``eager_impl`` (how ``benchmarks/bench_kernels`` counts
@@ -103,6 +115,10 @@ class OpStatsDelta:
     @property
     def pallas_calls(self) -> int:
         return self["pallas_calls"]
+
+    @property
+    def threshold_bisections(self) -> int:
+        return self["threshold_bisections"]
 
 
 @contextlib.contextmanager
@@ -230,19 +246,61 @@ def choco_move(x: jnp.ndarray, y: jnp.ndarray, mixed_y: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+_UNSIGNED = {2: jnp.uint16, 4: jnp.uint32}
+
+
+def _kth_largest_magnitude(x: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The k-th largest |x| in x's dtype, bit for bit what
+    ``lax.top_k(jnp.abs(x.reshape(-1)), k)[0][k - 1]`` returns, without
+    a sort.
+
+    Bisection over the w-bit pattern of the magnitude (module
+    docstring): every pattern with the sign cleared lies in [0, 2^(w-1)),
+    so w - 1 halvings of that range pin the largest ``t`` with
+    ``count(bits >= t) >= k``. Each halving is one reduction that clears
+    the sign bit, compares and counts in place, so no magnitude array is
+    kept; ``x`` keeps its shape, since flattening a tiled TPU array is a
+    relayout copy. Counts are int32, hence the bound on the size."""
+    if x.size >= 2**31:
+        raise ValueError(
+            f"TopK bisection counts in int32; a size-{x.size} vector "
+            "has 2^31 elements or more")
+    try:
+        uint = _UNSIGNED[x.dtype.itemsize]
+    except KeyError:
+        raise ValueError(
+            f"no bisection select for dtype {x.dtype}") from None
+    width = 8 * x.dtype.itemsize
+    sign_clear = uint((1 << (width - 1)) - 1)
+    _STATS["threshold_bisections"] += 1
+
+    def halve(_, lohi):
+        # Tied to the loop-variant bounds, x stays inside the body:
+        # otherwise XLA hoists its bitcast out of the loop as an array.
+        xv, (lo, hi) = jax.lax.optimization_barrier((x, lohi))
+        mid = lo + (hi - lo) // 2
+        bits = jax.lax.bitcast_convert_type(xv, uint) & sign_clear
+        ok = jnp.sum(bits >= mid, dtype=jnp.int32) >= k
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
+
+    lo, _ = jax.lax.fori_loop(0, width - 1, halve,
+                              (uint(0), uint(1 << (width - 1))))
+    return jax.lax.bitcast_convert_type(lo, x.dtype)
+
+
 def _threshold_impl(x: jnp.ndarray, k: int, mode: str) -> jnp.ndarray:
     """k-th largest |x| as a scalar in x's dtype. ``mode`` per
     ``registry.resolve_mode("topk_partials", ...)``: the candidate pass
-    runs as a kernel ("interpret"/"mosaic") or collapses to the plain
-    full-vector ``lax.top_k`` ("fallback"); all three produce the SAME
-    threshold bit-for-bit (see repro.kernels.topk)."""
+    runs as a kernel ("interpret"/"mosaic") or the bisection select reads
+    the whole vector ("fallback"); all three produce the SAME threshold
+    bit-for-bit (see repro.kernels.topk)."""
     flat = x.reshape(-1)
     if not 1 <= k <= flat.size:
         raise ValueError(
             f"TopK k={k} out of range for a size-{flat.size} vector")
     with jax.named_scope("compress.threshold"):
         if mode == "fallback":
-            return jax.lax.top_k(jnp.abs(flat), k)[0][k - 1]
+            return _kth_largest_magnitude(x, k)
         x2d, _ = _to_2d(x)
         cand = min(k, _TILE)
         _STATS["pallas_calls"] += 1
@@ -354,7 +412,7 @@ def _choco_topk_move_impl(x, y, mixed_y, gamma, *, k: int, tmode: str,
     d2d, n = _to_2d(diff)
     with jax.named_scope("compress.threshold"):
         if tmode == "fallback":
-            thresh = jax.lax.top_k(jnp.abs(diff), k)[0][k - 1]
+            thresh = _kth_largest_magnitude(diff, k)
         else:
             cand = min(k, _TILE)
             _STATS["pallas_calls"] += 1

@@ -218,7 +218,8 @@ _register("choco_move", mosaic=True, bitwise=False,
           parity=_parity_choco_move)
 _register("topk_partials", mosaic=False, bitwise=True,
           doc="per-tile top-cand magnitude candidates (lax.top_k "
-              "in-kernel: interpret/XLA-fallback only)",
+              "in-kernel: interpret only; the fallback selects the "
+              "threshold by bisection over the magnitude bits, no sort)",
           parity=_parity_topk)
 _register("topk_mask", mosaic=True, bitwise=True,
           doc="keep-or-zero against the TopK threshold scalar",

@@ -26,10 +26,17 @@ pad can enter the candidate set only when the true threshold is already
 0 — in which case the threshold is 0 either way.
 
 Pass 1 calls ``lax.top_k`` inside the kernel body, which the Mosaic TPU
-compiler does not lower — the registry marks it ``mosaic=False`` and the
-dispatcher falls back to the plain-XLA select on TPU (pass 2 stays a
-kernel there). Off-TPU both passes run in interpret mode, where they are
-validated bitwise against the ``repro.core.compression.TopK`` oracle.
+compiler does not lower — the registry marks it ``mosaic=False`` and on
+TPU the dispatcher replaces pass 1 and its select with a sort-free
+plain-XLA select (pass 2 stays a kernel there):
+``repro.kernels.ops._kth_largest_magnitude`` bisects over the magnitude's
+bit pattern, one compare-and-count reduction over the vector per bit
+(15 for bf16, 31 for f32). With the sign bit cleared, a float's bits order
+as an unsigned integer exactly as the magnitudes do, so the largest
+pattern ``t`` with ``count(bits >= t) >= k`` is bit for bit the k-th
+largest magnitude, ties included. Off-TPU both passes run in interpret
+mode; all three modes are validated bitwise against the
+``repro.core.compression.TopK`` oracle.
 """
 from __future__ import annotations
 

@@ -300,7 +300,8 @@ def test_op_stats_delta_scoping_and_reset_deprecation():
         with ops.op_stats_delta() as inner:
             ops.eager_impl("choco_move")(x, y, my, 0.5, interpret=True)
     # choco_move pads x, y, mixed_y: 3 round-trips, 1 launch per call.
-    assert inner.as_dict() == {"pad_roundtrips": 3, "pallas_calls": 1}
+    assert inner.as_dict() == {"pad_roundtrips": 3, "pallas_calls": 1,
+                               "threshold_bisections": 0}
     assert outer.pad_roundtrips == 6 and outer.pallas_calls == 2
     snapshot = ops.op_stats()
     snapshot["pallas_calls"] = 0      # a copy: a caller cannot clobber them
@@ -353,6 +354,101 @@ def test_topk_tpu_fallback_mode_is_bitwise():
     b = _top_k_compress(x, k=500, tmode="interpret", imask=True)
     assert jnp.array_equal(a, b)
     assert jnp.array_equal(a, ref.top_k_ref(x, 500))
+
+
+def _bits(a):
+    """The raw bit pattern, so -0.0 and +0.0 differ and NaN equals NaN."""
+    return jax.lax.bitcast_convert_type(
+        a, {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize])
+
+
+_BISECT_D = 33001        # not a multiple of the 256 x 128 tile
+
+
+def _bisect_case(case, key):
+    """(vector of size _BISECT_D, k) for one named threshold case."""
+    d = _BISECT_D
+    normal = jax.random.normal(key, (d,))
+    if case.startswith("normal"):
+        k = {"normal-k1": 1, "normal-k1pct": int(np.ceil(0.01 * d)),
+             "normal-kd": d}[case]
+        return normal, k
+    if case == "ties":       # 7 distinct magnitudes; k lands inside a run
+        levels = jnp.asarray([-2.0, -1.0, -0.5, 0.0, 0.5, 1.0, 2.0])
+        return levels[jax.random.randint(key, (d,), 0, 7)], d // 3
+    if case == "zeros":
+        return jnp.zeros((d,)), int(np.ceil(0.01 * d))
+    if case == "signed-zeros":   # 20 nonzeros, k reaches into the zeros
+        sign = jax.random.bernoulli(key, 0.5, (d,))
+        x = jnp.where(sign, -0.0, 0.0)
+        return x.at[:20].set(normal[:20]), 100
+    assert case == "infs"        # 3 of each sign; the 7th is finite
+    x = normal.at[:3].set(jnp.inf).at[3:6].set(-jnp.inf)
+    return x, 7
+
+
+@pytest.mark.parametrize("case", ["normal-k1", "normal-k1pct", "normal-kd",
+                                  "ties", "zeros", "signed-zeros", "infs"])
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_topk_bisection_threshold_is_bitwise(case, dtype):
+    """The TPU fallback's sort-free select returns, bit for bit, the
+    k-th largest magnitude that ``lax.top_k`` returns, under ``jit`` and
+    under a two-node ``vmap``."""
+    nodes = [_bisect_case(case, jax.random.key(seed)) for seed in (7, 8)]
+    k = nodes[0][1]
+    xs = jnp.stack([x for x, _ in nodes]).astype(dtype)
+
+    def want(x):
+        return jax.lax.top_k(jnp.abs(x.reshape(-1)), k)[0][k - 1]
+
+    def select(x):
+        return ops._kth_largest_magnitude(x, k)
+
+    got = jax.jit(select)(xs[0])
+    assert got.dtype == dtype
+    assert _bits(got) == _bits(want(xs[0])), (got, want(xs[0]))
+    got2 = jax.jit(jax.vmap(select))(xs)
+    assert jnp.array_equal(_bits(got2), _bits(jax.vmap(want)(xs))), got2
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_choco_topk_move_fallback_is_bitwise(dtype):
+    """The fused CHOCO TopK step with the bisection select (what a TPU
+    runs) equals the candidate-kernel path bit for bit."""
+    x, y, my, _ = _fused_inputs((3, 11001), dtype)
+    k = int(np.ceil(0.01 * x.size))
+    impl = ops.eager_impl("choco_topk_move")
+    a = impl(x, y, my, 0.6, k=k, tmode="fallback", interpret=True)
+    b = impl(x, y, my, 0.6, k=k, tmode="interpret", interpret=True)
+    for u, v in zip(a, b):
+        assert jnp.array_equal(_bits(u), _bits(v))
+
+
+def test_topk_fallback_lowers_without_sort():
+    """The fallback program holds no sort and no top_k (the candidate
+    path, its control, does), and the bisection counter ticks once per
+    traced select."""
+    import re
+
+    x = jax.random.normal(jax.random.key(4), (3001,), jnp.bfloat16)
+
+    def hlo_ops(tmode):
+        text = ops._top_k_compress.lower(x, k=31, tmode=tmode,
+                                         imask=True).as_text()
+        return set(re.findall(r"\b(?:stablehlo|chlo|mhlo)\.\w+", text))
+
+    assert "chlo.top_k" in hlo_ops("interpret")
+    fallback = hlo_ops("fallback")
+    assert "stablehlo.while" in fallback
+    assert not [op for op in fallback if "sort" in op or "top_k" in op]
+    impl = ops.eager_impl("top_k_compress")
+    with ops.op_stats_delta() as d:
+        impl(x, k=31, tmode="fallback", imask=True)
+        impl(x, k=31, tmode="interpret", imask=True)
+    assert d.threshold_bisections == 1
+    with pytest.raises(ValueError, match="2\\^31"):
+        jax.eval_shape(lambda t: ops._kth_largest_magnitude(t, 1),
+                       jax.ShapeDtypeStruct((2**31,), jnp.bfloat16))
 
 
 def test_on_tpu_constant_is_deprecated():

@@ -24,10 +24,10 @@ from repro.kernels import choco_fused, ops, registry
 SHAPE = (2048, 6144)      # qwen3-1.7b d_model x d_ff
 DTYPE = jnp.bfloat16
 TOPK = SHAPE[0] * SHAPE[1] // 2   # TopK's default frac
-# (the topk_mask case takes ~1.5 min: XLA's TPU top_k over 12.6 M values,
-# which finds the threshold before the Mosaic mask kernel runs. The fused
-# CHOCO-TopK kernel is compiled against a threshold spec instead, so that
-# sort is compiled once, not twice.)
+# (the topk_mask case also compiles the TPU threshold select, the bisection
+# over the magnitude bits that finds the threshold before the Mosaic mask
+# kernel runs. The fused CHOCO-TopK kernel is compiled against a threshold
+# spec instead.)
 
 
 @pytest.fixture(scope="module")
