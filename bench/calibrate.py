@@ -41,8 +41,8 @@ def readings(bench, cell_name: str, seeds, control_seeds,
     from bench.traffic import generator
 
     cell = bench.cell(cell_name)
-    if require_chip:
-        find_chips(int(cell["chips"]))
+    chips = int(cell["chips"])
+    devices = find_chips(chips) if require_chip else jax.devices()[:chips]
     config = bench.config(cell["config"])
     traffic = bench.traffic(cell["traffic"])
     model = config["model"]
@@ -62,8 +62,8 @@ def readings(bench, cell_name: str, seeds, control_seeds,
         rows.append(row)
         emit(json.dumps(row), flush=True)
 
-    ref = Reference(model, traffic, "float32", rms_eps=eps)
-    program = Program(config, traffic)
+    ref = Reference(model, traffic, "float32", rms_eps=eps, devices=devices)
+    program = Program(config, traffic, devices)
     steps = int(bench.limits(cell_name).get("steps", 1))
     for i, seed in enumerate(seeds):
         pool = generator.make_pool(traffic, model["vocab_size"], seed)
@@ -78,10 +78,12 @@ def readings(bench, cell_name: str, seeds, control_seeds,
         record("program", seed, check.numbers(prog, want), prog, want)
     del program
     gc.collect()
-    variants = [("control", Reference(model, traffic, "fp8", rms_eps=eps))]
+    variants = [("control", Reference(model, traffic, "fp8", rms_eps=eps,
+                                      devices=devices))]
     for fault in ("half_batch", "no_gossip"):
         variants.append((fault, Reference(model, traffic, "float32",
-                                          faults=(fault,), rms_eps=eps)))
+                                          faults=(fault,), rms_eps=eps,
+                                          devices=devices)))
     for seed in control_seeds:
         pool = generator.make_pool(traffic, model["vocab_size"], seed)
         with jax.default_matmul_precision("highest"):

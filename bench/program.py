@@ -1,8 +1,10 @@
 """The program under test, built as ``repro.launch.train.main`` builds it.
 
 One ``RoundExecutor`` with fused dispatch, on the engine ``resolve_engine``
-picks; one stacked ``DFLState`` built by one jitted program in the
-executor's state layout; batches stacked by ``stack_round_batches`` and
+picks: a cell on one chip stacks its nodes there, a cell on several chips
+holds one node a chip on a mesh of the cell's devices and must resolve to
+the sparse engine; one stacked ``DFLState`` built by one jitted program in
+the executor's state layout; batches stacked by ``stack_round_batches`` and
 moved to the device by the same call ``train.py`` uses, built ahead on a
 ``HostPrefetcher`` thread. The weights are the benchmark's own seeded draw
 (``bench.reference.model.make_weights``), placed into the program's
@@ -12,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +58,8 @@ def path_name(path) -> str:
 class Program:
     """Everything the window drives, for one cell."""
 
-    def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any]):
+    def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
+                 devices: Optional[Sequence[Any]] = None):
         from repro.core import (DFLConfig, RoundExecutor, init_state,
                                 make_compressor)
         from repro.core.substrate import make_mesh
@@ -77,12 +80,23 @@ class Program:
         dcfg = DFLConfig(tau1=self.tau1, tau2=self.tau2,
                          topology=make_topology(traffic["topology"], n),
                          compression=comp, gamma=float(traffic["gamma"]))
-        mesh = (make_mesh((n,), ("nodes",)) if len(jax.devices()) == n
-                else None)
+        devices = list(devices or jax.devices()[:1])
+        self.mesh = mesh = (
+            make_mesh((len(devices),), ("nodes",), devices=devices)
+            if len(devices) > 1 else None)
         self.executor = RoundExecutor(
             dcfg, lambda p, b, k: train_loss(p, b, cfg, k), opt,
             engine="auto", mesh=mesh, node_axes=("nodes",),
             use_kernels=bool(traffic["use_kernels"]), dynamic=True)
+        if mesh is not None and self.executor.engine != "sparse":
+            raise ValueError(
+                f"a cell on {len(devices)} chips runs one node a chip on the "
+                f"sparse engine; {n} nodes on a {traffic['topology']} "
+                f"resolve to the {self.executor.engine} engine")
+        # the seeded weights land where the state is built from them
+        replicated = (jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec()) if mesh is not None
+            else None)
 
         model = dict(config["model"])
         self.specs = ref_model.param_specs(model)
@@ -102,7 +116,8 @@ class Program:
                              f"weights differ: {sorted(self.names)} vs "
                              f"{sorted(self.specs)}")
         self.weights = jax.jit(
-            lambda key: ref_model.make_weights(self.specs, model, key))
+            lambda key: ref_model.make_weights(self.specs, model, key),
+            out_shardings=replicated)
 
         def build(flat_w, key):
             params = jax.tree_util.tree_unflatten(
@@ -127,41 +142,47 @@ class Program:
         return state
 
     def _stats_fn(self, params, hat, w0):
-        """Per node and leaf ||x_n - x_0||, per leaf max_n ||x_n - mean||,
-        per node and leaf ||y_n|| (C-DFL), all in float32."""
-        def norm(v):
-            return jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32))))
+        """Per leaf, over the nodes (its leading axis): ||x_n - x_0||,
+        max_n ||x_n - mean||, ||y_n|| (C-DFL) and the fixed samples, all in
+        float32. Each node's part is reduced where it lies, so a state held
+        one node a chip is never gathered; only the nodes' mean crosses
+        chips."""
+        def norms(v):       # [n, ...] -> [n]
+            return jnp.sqrt(jnp.sum(jnp.square(v), axis=tuple(
+                range(1, v.ndim))))
+
+        def sample(v, k):
+            return v.reshape(v.shape[0], -1)[:, self._sample_idx[k]].astype(
+                jnp.float32)
 
         leaves = dict(zip(self.names, jax.tree_util.tree_leaves(params)))
-        change = [{k: norm(v[i].astype(jnp.float32)
-                           - w0[k].astype(jnp.float32))
-                   for k, v in leaves.items()} for i in range(self.n)]
-        spread = {}
+        out = {"change": {}, "spread": {}, "sample": {}}
         for k, v in leaves.items():
             v32 = v.astype(jnp.float32)
-            mean = jnp.mean(v32, axis=0)
-            spread[k] = jnp.max(jnp.stack([norm(v32[i] - mean)
-                                           for i in range(self.n)]))
-        def sample(tree):
-            return [{k: v[i].reshape(-1)[self._sample_idx[k]].astype(
-                jnp.float32) for k, v in tree.items()} for i in range(self.n)]
-
-        out = {"change": change, "spread": spread, "sample": sample(leaves)}
+            out["change"][k] = norms(v32 - w0[k].astype(jnp.float32)[None])
+            out["spread"][k] = jnp.max(norms(v32 - jnp.mean(v32, axis=0)[
+                None]))
+            out["sample"][k] = sample(v, k)
         if hat is not None:
             hl = dict(zip(self.names, jax.tree_util.tree_leaves(hat)))
-            out["hat"] = [{k: norm(v[i]) for k, v in hl.items()}
-                          for i in range(self.n)]
-            out["hat_sample"] = sample(hl)
+            out["hat"] = {k: norms(v.astype(jnp.float32))
+                          for k, v in hl.items()}
+            out["hat_sample"] = {k: sample(v, k) for k, v in hl.items()}
         return out
 
     def stats(self, state, seed: int) -> Dict[str, Any]:
-        """The first supersteps' readings on the host: norms as floats,
-        samples as float32 arrays."""
+        """The first supersteps' readings on the host, as ``check.numbers``
+        takes them: per node a dict of norms (floats) or samples (float32
+        arrays) by leaf, and the spread by leaf."""
         w0 = self.weights(ref_model.seed_key(seed))
-        out = jax.device_get(self._stats(state.params, state.hat_params, w0))
+        got = jax.device_get(self._stats(state.params, state.hat_params, w0))
         del w0
-        return jax.tree_util.tree_map(
-            lambda v: float(v) if np.ndim(v) == 0 else np.asarray(v), out)
+        out = {"spread": {k: float(v) for k, v in got.pop("spread").items()}}
+        for name, by_leaf in got.items():
+            out[name] = [{k: (float(v[i]) if np.ndim(v) == 1
+                              else np.asarray(v[i]))
+                          for k, v in by_leaf.items()} for i in range(self.n)]
+        return out
 
     # -- batches and dispatch -------------------------------------------------
 
@@ -222,22 +243,38 @@ def first_steps(program: Program, state, pool, steps: int, seed: int):
     return state, prog
 
 
+@dataclasses.dataclass
+class Window:
+    """What one measured window did: the state it leaves, its wall time
+    (first batch's build to the end of the last superstep), the supersteps
+    run, their per-round losses, each superstep's wall time on the host
+    clock, and each superstep's metrics as the device returned them."""
+
+    state: Any
+    wall: float
+    done: int
+    losses: List[float]
+    step_s: List[float]
+    metrics: List[Dict[str, Any]]
+
+
 def run_window(program: Program, state, pool, seconds: float,
-               first: int = 0):
+               first: int = 0) -> Window:
     """Whole supersteps, at least two, while one more fits in ``seconds``
     at the mean pace so far, as ``train.py``'s loop runs them with
     ``--log-every 1``: the next superstep's batches are built and moved on
     a ``HostPrefetcher`` thread while the device runs, and the host waits
-    for each superstep's metrics. Returns the state, the wall time from the
-    first batch's build to the end of the last superstep, the supersteps
-    run and their per-round losses."""
+    for each superstep's loss. Each superstep's metrics stay on the
+    device, to be read once the window has closed."""
     from repro.core import HostPrefetcher
 
     prefetch = HostPrefetcher()
     losses: List[float] = []
+    step_s: List[float] = []
+    kept: List[Dict[str, Any]] = []
     done = 0
     i = first
-    t0 = time.perf_counter()
+    t0 = last = time.perf_counter()
     prefetch.schedule(program.batches, pool[i % len(pool)], meta=i)
     try:
         while True:
@@ -250,10 +287,28 @@ def run_window(program: Program, state, pool, seconds: float,
             with jax.profiler.TraceAnnotation("bench.wait"):
                 loss = np.asarray(jax.block_until_ready(metrics["loss"]))
             losses.extend(float(v) for v in loss)
+            kept.append(metrics)
+            del metrics
             done += 1
-            wall = time.perf_counter() - t0
+            now = time.perf_counter()
+            step_s.append(now - last)
+            last = now
+            wall = now - t0
             if done >= 2 and wall + wall / done > seconds:
                 break
     finally:
         prefetch.close()
-    return state, wall, done, losses
+    return Window(state, wall, done, losses, step_s, kept)
+
+
+def round_metrics(kept: List[Dict[str, Any]]) -> Dict[str, float]:
+    """Every per-round metric of the program but the loss, summed over the
+    rounds of the supersteps ``kept``: the program's counters, read once
+    the window has closed."""
+    out: Dict[str, float] = {}
+    for metrics in jax.device_get(kept):
+        for name, v in metrics.items():
+            if name != "loss":
+                out[name] = out.get(name, 0.0) + float(
+                    np.sum(np.asarray(v, np.float64)))
+    return out

@@ -18,8 +18,11 @@ state, traffic)``). A later mix adds its part's file and edits none.
 Arithmetic is float32 (or the fp8 control, ``model.Ops``); every stored
 tensor (parameters and the shared estimates y) is rounded to the dtype the
 configuration states after each update, as the configuration stores it.
-Nodes take their local steps one at a time, so the reference fits on one
-chip. Nothing here comes from the program.
+Each node takes its local steps on a device of its own where the cell has
+several (node i on ``devices[i % len(devices)]``), one node after another
+where it has one chip, so the reference fits; the gossip step runs on the
+first device, with every node's tree copied there. Nothing here comes from
+the program.
 
 ``faults`` plants the faults the correctness check must catch, for reading
 them on the chip: "half_batch" (loss and gradient over the first half of
@@ -28,7 +31,7 @@ each sequence only) and "no_gossip" (the exchange between nodes left out).
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -55,12 +58,13 @@ class Reference:
 
     def __init__(self, model: Dict[str, Any], traffic: Dict[str, Any],
                  precision: str = "float32", faults: Sequence[str] = (),
-                 rms_eps: float = 1e-6):
+                 rms_eps: float = 1e-6, devices: Optional[Sequence] = None):
         self.model, self.traffic = model, traffic
         self.faults = tuple(faults)
         self.ops = ref_model.Ops(precision, rms_eps)
         self.specs = ref_model.param_specs(model)
         self.n = int(traffic["nodes"])
+        self.devices = list(devices or jax.devices()[:1])
         self.c = part("topology", traffic["topology"]).matrix(self.n)
         comp = traffic.get("compressor")
         self.compress = None
@@ -148,32 +152,44 @@ class Reference:
         parameters' change and of the shared estimates, per leaf the
         nodes' largest distance from their mean, and per leaf the norm of
         node 0's first gradient."""
+        home = [self.devices[i % len(self.devices)] for i in range(self.n)]
+        first = self.devices[0]
         w0 = self.weights(key)
-        nodes = [{k: jnp.array(v, copy=True) for k, v in w0.items()}
-                 for _ in range(self.n)]
+        nodes = [jax.device_put(w0, d, may_alias=False) for d in home]
         del w0
         opts = [self.optim.init(nd) for nd in nodes]
         hats = None
         if self.compress is not None:
-            hats = [{k: jnp.zeros(v.shape, v.dtype) for k, v in nd.items()}
-                    for nd in nodes]
+            hats = [{k: jnp.zeros(v.shape, v.dtype, device=first)
+                     for k, v in nd.items()} for nd in nodes]
         losses, grad0 = [], None
-        pos = jnp.arange(rounds[0]["tokens"].shape[-1], dtype=jnp.int32)
+        seq = rounds[0]["tokens"].shape[-1]
+        pos = [jax.device_put(np.arange(seq, dtype=np.int32), d)
+               for d in home]
         for rb in rounds:
             toks, labs = rb["tokens"], rb["labels"]
             per_round = []
+            # each node back on its device before any steps, so that the
+            # first holds one node's copy and one step's temporaries
+            nodes = [jax.device_put(nd, d) for nd, d in zip(nodes, home)]
             for t in range(toks.shape[0]):
+                # dispatched node after node without a wait, so that
+                # nodes on devices of their own step at the same time
                 for i in range(self.n):
                     nodes[i], opts[i], loss, gn = self._update(
-                        nodes[i], opts[i], jnp.asarray(toks[t, i]),
-                        jnp.asarray(labs[t, i]), pos)
-                    per_round.append(float(loss))
+                        nodes[i], opts[i],
+                        jax.device_put(toks[t, i], home[i]),
+                        jax.device_put(labs[t, i], home[i]), pos[i])
+                    per_round.append(loss)
                     if grad0 is None:
-                        grad0 = _host(gn)
-            losses.append(float(np.mean(per_round)))
+                        grad0 = gn
+            losses.append(float(np.mean([float(v) for v in per_round])))
             for _ in range(int(self.traffic["tau2"])):
-                nodes, hats = self._gossip(nodes, hats)
-        w0 = self.weights(key)
+                nodes, hats = self._gossip(
+                    [jax.device_put(nd, first) for nd in nodes], hats)
+        nodes = [jax.device_put(nd, first) for nd in nodes]
+        grad0 = _host(grad0)
+        w0 = jax.device_put(self.weights(key), first)
         out = {"loss": losses,
                "change": [_host(self._change(nd, w0)) for nd in nodes],
                "spread": _host(self._spread(nodes)),

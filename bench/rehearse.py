@@ -4,10 +4,14 @@ and print what each needs of the device's memory.
     JAX_PLATFORMS=cpu python3 bench/rehearse.py --workload <cell> [--vocab N]
 
 Compiles the superstep the window drives (the Mosaic kernels as the chip
-would compile them) and the reference's local step and gossip step, each
-from shapes alone, and prints ``memory_analysis()`` of each in bytes:
-arguments, outputs, temporaries, aliased, and their total. ``--vocab``
-overrides the configuration's vocabulary, to size a cut before a chip run.
+would compile them; a cell on several chips over the topology's first
+devices, one node a chip) and the reference's local step and gossip step,
+each from shapes alone, and prints ``memory_analysis()`` of each in bytes
+(for the superstep, per device): arguments, outputs, temporaries, aliased,
+and their total; the superstep's count of collective-permutes; and the
+same sizes for the program's readings of its first supersteps
+(``stats``). ``--vocab`` overrides the configuration's vocabulary, to size
+a cut before a chip run.
 A compile that passes here is not a chip run and gives no time.
 """
 from __future__ import annotations
@@ -63,24 +67,45 @@ def main(argv=None) -> int:
     model = config["model"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=args.topology)
-    one = SingleDeviceSharding(topo.devices[0])
+    devices = topo.devices[:int(cell["chips"])]
+    one = SingleDeviceSharding(devices[0])
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
             tree)
 
-    program = Program(config, traffic)
+    program = Program(config, traffic, devices)
     key = jax.eval_shape(lambda: seed_key(1))
     w = jax.eval_shape(program.weights, key)
-    state = on_chip(jax.eval_shape(program._build, w, key))
+    state = jax.eval_shape(program._build, w, key)
+    if program.executor.state_sharding is None:
+        state = on_chip(state)
+    else:                       # one node a chip, on the cell's mesh
+        state = jax.tree_util.tree_map(
+            lambda sh, sub: jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=sh), sub),
+            program.executor.state_sharding, state)
     k, t1, n = traffic["superstep"], traffic["tau1"], traffic["nodes"]
     b, s = traffic["batch"], traffic["seq"]
-    tok = jax.ShapeDtypeStruct((k, t1, n, b, s), jnp.int32, sharding=one)
+    # batches go where the superstep wants them, as ``Program.warm`` leaves
+    # them, on a mesh; a one-chip superstep is pinned to the chip
+    tok = jax.ShapeDtypeStruct(
+        (k, t1, n, b, s), jnp.int32,
+        sharding=one if program.executor.state_sharding is None else None)
     batches = {"tokens": tok, "labels": tok}
     taus = np.zeros((k, program.executor.row_width), np.int32)
-    report = {"superstep": sizes(program.executor.lower_superstep(
-        state, batches, taus).compile())}
+    compiled = program.executor.lower_superstep(state, batches,
+                                                taus).compile()
+    report = {"superstep": sizes(compiled),
+              "collective_permutes": compiled.as_text().count(
+                  "collective-permute-start(")}
+    w0 = jax.eval_shape(program.weights, key)       # where stats take it
+    if program.mesh is None:
+        w0 = on_chip(w0)
+    report["stats"] = sizes(program._stats.lower(
+        state.params, state.hat_params, w0).compile())
 
     ref = Reference(model, traffic, "float32")
     wr = on_chip(w)

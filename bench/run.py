@@ -3,16 +3,22 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 From the checkout's root. The cell (``BENCHMARK.json``) names a
-configuration and a traffic mix; see ``bench/spec.py`` for where each
-piece lives. One run:
+configuration, a traffic mix and its chips (one node a chip where it asks
+for several); see ``bench/spec.py`` for where each piece lives. One run:
 
   1. set-up: builds the program's executor and its state from the seed,
      compiles the cell's one superstep shape (JAX's compile cache is kept
      in ``<checkout>/.jax_cache``), makes the token pool, and dispatches
      the first superstep, whose result the correctness check keeps;
   2. the window: whole supersteps until ``--seconds`` have passed (with
-     ``--trace 1`` under the profiler);
-  3. reads the device's peak memory, frees the program's state, runs the
+     ``--trace 1`` under the profiler, keeping each superstep's metrics);
+     each superstep's wall time is taken, and the slowest is printed
+     beside the median;
+  3. reads the device's peak memory; with ``--trace 1`` reduces the trace
+     (busy time, ops, idle gaps, device seconds per named scope of the
+     compiled module, ``bench/scopes.py``) and the program's per-round
+     counters summed over the window into the summary the per-layer
+     readers (``bench/metrics``) get; frees the program's state, runs the
      plain reference over the first superstep and compares.
 
 The last line of standard output is the result as one JSON object; the
@@ -61,9 +67,14 @@ def use_compile_cache() -> None:
     # no size cap: a capped cache evicts by access-time files and drops
     # entries; the benchmark's few programs are written once per checkout
     jax.config.update("jax_compilation_cache_max_size", -1)
+    # the key leaves metadata out by default: an executable that an older
+    # program compiled without the named scopes would be loaded in place of
+    # this one, and a traced run would read no scope
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def find_chips(chips: int):
+    """The cell's first ``chips`` TPU devices."""
     import jax
 
     devices = jax.devices()
@@ -73,7 +84,7 @@ def find_chips(chips: int):
     if len(devices) < chips:
         raise NoChip(f"the cell needs {chips} chips, JAX found "
                      f"{len(devices)}")
-    return devices
+    return devices[:chips]
 
 
 def run_cell(bench, cell_name: str, seed: int, seconds: float, trace: bool,
@@ -84,9 +95,10 @@ def run_cell(bench, cell_name: str, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
 
-    from bench import check, trace as trace_lib
+    from bench import check, scopes, trace as trace_lib
     from bench.flops import model_flops_per_token
-    from bench.program import Program, first_steps, run_window
+    from bench.program import (Program, first_steps, round_metrics,
+                               run_window)
     from bench.reference.dfl import Reference
     from bench.reference.model import dtype_of, seed_key
     from bench.traffic import generator
@@ -97,8 +109,8 @@ def run_cell(bench, cell_name: str, seed: int, seconds: float, trace: bool,
     def mark(name):
         marks.append((name, time.perf_counter()))
 
-    devices = find_chips(int(cell["chips"])) if require_chip else \
-        jax.devices()
+    chips = int(cell["chips"])
+    devices = find_chips(chips) if require_chip else jax.devices()[:chips]
     mark("devices")
     peaks = bench.peaks(devices[0].device_kind) if trace else None
     config = bench.config(cell["config"])
@@ -107,8 +119,13 @@ def run_cell(bench, cell_name: str, seed: int, seconds: float, trace: bool,
 
     # -- set-up -----------------------------------------------------------
     steps = int(bench.limits(cell_name).get("steps", 1))
-    program = Program(config, traffic)
+    program = Program(config, traffic, devices)
     mark("program")
+    if program.mesh is not None:
+        log("ring order (node: device id, coords): " + ", ".join(
+            f"{i}: {d.id} {getattr(d, 'coords', None)}"
+            for i, d in enumerate(program.mesh.devices.flat)),
+            file=sys.stderr)
     pool = generator.make_pool(traffic, model["vocab_size"], seed)
     mark("pool")
     state = program.build_state(seed)
@@ -133,11 +150,15 @@ def run_cell(bench, cell_name: str, seed: int, seconds: float, trace: bool,
         opts.host_tracer_level = 1
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
     with jax.profiler.TraceAnnotation(trace_lib.WINDOW):
-        state, wall, done, losses = run_window(program, state, pool,
-                                               seconds, first=steps)
-        jax.block_until_ready(state)
+        win = run_window(program, state, pool, seconds, first=steps)
+        jax.block_until_ready(win.state)
     if trace:
         jax.profiler.stop_trace()
+    state, wall, done, losses = win.state, win.wall, win.done, win.losses
+    slow = max(range(done), key=win.step_s.__getitem__)
+    log(f"superstep wall (s): median {np.median(win.step_s):.4f}, slowest "
+        f"{win.step_s[slow]:.4f} (superstep {slow} of {done})",
+        file=sys.stderr)
     if program.executor.compile_count != compiles:
         raise RuntimeError(
             f"{program.executor.compile_count - compiles} superstep "
@@ -154,12 +175,21 @@ def run_cell(bench, cell_name: str, seed: int, seconds: float, trace: bool,
               for shape, dt, _ in (program.specs[k] for k in program.names)]
     summary = None
     if trace:
+        hlo = program.compiled.as_text()
+        kernels = trace_lib.kernel_names(hlo)
         ops, spans = trace_lib.load(trace_dir)
-        summary = trace_lib.reduce_events(
-            ops, spans, trace_lib.kernel_names(program.compiled.as_text()))
+        summary = trace_lib.reduce_events(ops, spans, kernels)
+        summary.update(scopes.reduce_scopes(ops, spans,
+                                            scopes.scope_paths(hlo), kernels))
+        del hlo, ops, spans
         shutil.rmtree(trace_dir, ignore_errors=True)
+        log(scopes.tables(summary, summary.get("busy_s", 0.0)),
+            file=sys.stderr)
         summary["kernels"] = {k: v for k, v in summary.get("ops", {}).items()
                               if k.endswith("kernel")}
+        summary["round_metrics"] = round_metrics(win.metrics)
+        log("program counters over the window: "
+            + json.dumps(summary["round_metrics"]), file=sys.stderr)
         summary.update(
             wall_s=wall, tokens=tokens, rounds=done * int(
                 traffic["superstep"]),
@@ -168,13 +198,14 @@ def run_cell(bench, cell_name: str, seed: int, seconds: float, trace: bool,
             flops_per_token=model_flops_per_token(
                 model, int(traffic["seq"]),
                 lambda k: bench.layer_kind("flops", k)))
-    del state, program
+    del state, win, program
     gc.collect()
 
     # -- correctness ------------------------------------------------------
     t_ref = time.perf_counter()
     ref = Reference(model, traffic, "float32",
-                    rms_eps=config.get("reference", {}).get("rms_eps", 1e-6))
+                    rms_eps=config.get("reference", {}).get("rms_eps", 1e-6),
+                    devices=devices)
     with jax.default_matmul_precision("highest"):
         want = ref.superstep(seed_key(seed),
                              [r for rounds in pool[:steps] for r in rounds])

@@ -19,6 +19,8 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 WINDOW = "bench.window"
+HARNESS_SPAN = "bench."
+PROGRAM_SPAN = "dfl."
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 
@@ -99,8 +101,8 @@ def reduce_events(device_ops: Dict[str, List[Tuple[str, float, float]]],
     """The reduction itself, on plain lists (times in ns).
 
     ``device_ops``: per device, (op name, start, end); ``host_spans``:
-    (span name, start, end) of the harness's host spans, one of them
-    ``bench.window``."""
+    (span name, start, end) of host spans, one of them ``bench.window``;
+    idle gaps are labelled by the harness's (``bench.*``) alone."""
     kernels = kernels or {}
     win = [(s, e) for n, s, e in host_spans if n == WINDOW]
     if not win or not device_ops:
@@ -110,7 +112,8 @@ def reduce_events(device_ops: Dict[str, List[Tuple[str, float, float]]],
     per_op: Dict[str, float] = {}
     busy_total = 0.0
     gap_by: Dict[str, float] = {}
-    spans = [(n, s, e) for n, s, e in host_spans if n != WINDOW]
+    spans = [(n, s, e) for n, s, e in host_spans
+             if n.startswith(HARNESS_SPAN) and n != WINDOW]
     for ops in device_ops.values():
         clipped = []
         for name, s, e in ops:
@@ -143,8 +146,8 @@ def reduce_events(device_ops: Dict[str, List[Tuple[str, float, float]]],
 
 def load(trace_dir: str) -> Tuple[Dict[str, List[Tuple[str, float, float]]],
                                   List[Tuple[str, float, float]]]:
-    """Device op events and harness host spans of the newest trace under
-    ``trace_dir``."""
+    """Device op events, and the harness's (``bench.*``) and the program's
+    (``dfl.*``) host spans, of the newest trace under ``trace_dir``."""
     from jax.profiler import ProfileData
 
     files = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile",
@@ -164,7 +167,7 @@ def load(trace_dir: str) -> Tuple[Dict[str, List[Tuple[str, float, float]]],
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith("bench."):
+                    if e.name.startswith((HARNESS_SPAN, PROGRAM_SPAN)):
                         host_spans.append((e.name, e.start_ns, e.end_ns))
     return device_ops, host_spans
 

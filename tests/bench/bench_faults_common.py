@@ -6,7 +6,8 @@ computed with fp8 matrix products and put in the program's place, is not.
 Each fault a training cell can have, planted in the program underneath an
 otherwise whole run, makes the run not correct: a superstep that returns
 its state unchanged, half of each batch left out of the loss, and the
-gossip exchange between nodes left out."""
+gossip exchange between nodes (between chips, on the sparse engine) left
+out."""
 import jax
 import jax.numpy as jnp
 
@@ -74,10 +75,14 @@ def _half_batch(monkeypatch):
 
 
 def _no_gossip(monkeypatch):
+    # nodes stacked on one device mix densely; one node a device exchanges
+    # with its ring neighbours by collective-permute
     from repro.core import mixing
 
     monkeypatch.setattr(mixing, "mix_dense",
                         lambda tree, topology, edge_mask=None: tree)
+    monkeypatch.setattr(mixing, "mix_ppermute_shifts",
+                        lambda params, *args, **kwargs: params)
 
 
 FAULTS = {"unchanged": _unchanged, "half_batch": _half_batch,

@@ -2,13 +2,17 @@
 metrics and layer kinds by name from files, so that a later cell,
 configuration or metric is added as files and entries alone; and the
 declared ``BENCHMARK.json`` keeps to the shape the contract gives it."""
+import hashlib
 import json
 import os
 import re
+import shutil
 
 import pytest
 
+from bench.flops import model_flops_per_token
 from bench.spec import ROOT as REPO, Bench
+from conftest import make_tiny_bench, tiny_cells
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -100,7 +104,84 @@ def test_unknown_cell_and_missing_files_raise(tiny_bench):
     with pytest.raises(FileNotFoundError):
         tiny_bench.metric_reader("no_such_metric")
     with pytest.raises(FileNotFoundError):
-        tiny_bench.layer_kind("flops", "moe")
+        tiny_bench.layer_kind("flops", "no_such_kind")
+
+
+def test_a_multichip_cell_holds_one_node_a_chip(decl):
+    # a cell asks for four chips only for what exists across chips: here
+    # the gossip between nodes that each hold a chip of their own; a small
+    # CPU cell on as many devices stands for it
+    bench = Bench(REPO)
+    small = tiny_cells()
+    for w in decl["workloads"]:
+        if w["chips"] > 1:
+            assert bench.traffic(w["traffic"])["nodes"] == w["chips"]
+            assert any(body["stands_for"] == w["name"]
+                       for body in small.values())
+    for body in small.values():
+        if body.get("chips", 1) > 1:
+            assert body["chips"] == body["traffic"]["nodes"]
+    ring4 = bench.traffic("ring4")
+    assert ring4["nodes"] == 4 and ring4["topology"] == "ring"
+    assert ring4["compressor"] is None
+    assert small["tiny-qwen.ring4"]["chips"] == 4
+
+
+def _digests(root):
+    out = {}
+    for d, _, files in os.walk(root):
+        for f in files:
+            path = os.path.join(d, f)
+            with open(path, "rb") as fh:
+                out[os.path.relpath(path, root)] = hashlib.sha256(
+                    fh.read()).hexdigest()
+    return out
+
+
+def test_a_new_layer_kind_and_small_cell_are_added_as_files_alone(tmp_path):
+    # a later configuration brings a layer kind (its model FLOPs and its
+    # plain reference) and a small CPU cell standing for its real cell, as
+    # new files in a copy of the checkout; the benchmark finds them and no
+    # file that was there changes
+    src = tmp_path / "checkout"
+    os.makedirs(src)
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), src)
+    for d in ("bench", os.path.join("tests", "bench")):
+        shutil.copytree(os.path.join(REPO, d), src / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    before = _digests(src)
+    (src / "bench" / "flops" / "moe_test.py").write_text(
+        "def flops_per_token(model, seq):\n"
+        "    return 6.0 * 3 * model['d_model'] * model['d_ff']\n")
+    (src / "bench" / "reference" / "moe_test.py").write_text(
+        "def params(model):\n"
+        "    return {'ln2': ((model['d_model'],), 'param', ('zeros', 0.0))}"
+        "\n\n\ndef apply(p, h, pos, model, ops):\n    return h\n")
+    body = tiny_cells()["tiny-qwen.dfl"]
+    body["config"] = dict(body["config"], name="tiny-moe")
+    body["config"]["model"] = dict(
+        body["config"]["model"], pattern=[{"mixer": "attn",
+                                           "ffn": "moe_test"}])
+    body["stands_for"] = "qwen3-1.7b.cdfl-topk"
+    with open(src / "tests" / "bench" / "tiny" / "tiny-moe.dfl.json",
+              "w") as f:
+        json.dump(body, f)
+    after = _digests(src)
+    assert {k: after[k] for k in before} == before
+    bench = Bench(make_tiny_bench(str(tmp_path / "small"), source=str(src)))
+    cell = bench.cell("tiny-moe.dfl")
+    model = bench.config(cell["config"])["model"]
+    assert list(bench.layer_kind("reference", "moe_test").params(model)) \
+        == ["ln2"]
+    total = model_flops_per_token(model, 32,
+                                  lambda k: bench.layer_kind("flops", k))
+    assert total == pytest.approx(
+        6 * 64 * 256 + 2 * 6.0 * 3 * 64 * 128
+        + 2 * bench.layer_kind("flops", "attn").flops_per_token(model, 32))
+    # the new cell takes the metrics of the real cell it stands for
+    assert "topk_mask_roofline" in [m["name"]
+                                    for m in bench.per_layer("tiny-moe.dfl")]
+    assert set(tiny_cells(str(src))) == set(tiny_cells()) | {"tiny-moe.dfl"}
 
 
 def test_reference_parts_are_found_by_name(tiny_bench, monkeypatch):

@@ -1,14 +1,25 @@
-"""Device time by the program's named phases: scope paths read from an
+"""Device time by the program's named scopes: scope paths read from an
 optimized module's ``op_name`` metadata (a callee inherits its caller's
-scopes), device seconds per scope, backward by the VJP marker, unscoped
-busy time, and idle gaps labelled by the innermost ``dfl.*`` host span."""
+scopes), device seconds per scope (every named scope of the module, not
+only the engine's), backward by the VJP marker, unscoped busy time, idle
+gaps labelled by the innermost ``dfl.*`` host span, and the per-layer
+readers of the engine's phases."""
 import re
 
 import pytest
 
 from bench import scopes, trace
 from bench.program import Program
+from bench.spec import Bench
 from bench.traffic import generator
+
+PHASE_READERS = ("local_s_per_round", "gossip_s_per_round",
+                 "backward_s_per_round", "optimizer_s_per_round",
+                 "topk_threshold_s_per_round")
+
+
+def reader(name):
+    return Bench().metric_reader(name)
 
 MS = 1_000_000  # ns
 
@@ -120,7 +131,7 @@ def test_a_module_without_scopes_reads_none_never_zero():
     s = scopes.reduce_scopes(ops, spans, paths)
     assert s["scopes"] is None and s["backward_s"] is None
     assert s["unscoped_s"] is None
-    assert set(scopes.per_round(s, 4).values()) == {None}
+    assert {reader(n)(dict(s, rounds=4)) for n in PHASE_READERS} == {None}
     assert scopes.reduce_scopes({}, spans, paths) == {}
     assert scopes.reduce_scopes(ops, spans[1:], paths) == {}
 
@@ -133,10 +144,40 @@ def test_a_module_without_scopes_reads_none_never_zero():
     ("topk_threshold_s_per_round", 0.020),
 ])
 def test_per_round_reads_each_phase(name, want):
+    # each phase's per-layer metric, read by its own file under
+    # bench/metrics from the summary a traced run builds
     ops, spans = _events()
     s = scopes.reduce_scopes(ops, spans, scopes.scope_paths(HLO))
-    assert scopes.per_round(s, 2)[name] == pytest.approx(want / 2)
-    assert scopes.per_round(s, 0)[name] is None
+    read = reader(name)
+    assert read(dict(s, rounds=2)) == pytest.approx(want / 2)
+    assert read(dict(s, rounds=0)) is None
+    assert read({"rounds": 2}) is None
+
+
+def test_every_named_scope_is_reported_not_only_the_engines():
+    # a scope no file of the benchmark names (a later program's
+    # ``moe.experts``) is reported by name; argument names (an ``op_name``
+    # with no path) and einsum specs are no scopes
+    hlo = HLO.replace("dfl.local/dfl.local.update/sub",
+                      "dfl.local/moe.experts/sub").replace(
+        "  ROOT %reduce.14", "  %copy.15 = f32[8]{0} copy(%x), metadata="
+        "{op_name=\"state.params[\\'embed\\']\"}\n"
+        "  %dot.16 = f32[8]{0} dot(%x, %x), metadata={op_name=\"jit("
+        "superstep)/while/body/dfl.local/...d,df->...f/dot_general\"}\n"
+        "  ROOT %reduce.14")
+    p = scopes.scope_paths(hlo)
+    assert p["sub.6"] == ("dfl.local", "moe.experts")
+    assert p["copy.15"] == () and p["dot.16"] == ("dfl.local",)
+    assert scopes.phase(p["sub.6"]) == "moe.experts"
+    ops, spans = _events()
+    s = scopes.reduce_scopes(ops, spans, p)
+    assert set(s["scopes"]) == {"dfl.local", "dfl.gossip",
+                                "compress.threshold", "moe.experts"}
+    assert s["scopes"]["moe.experts"] == pytest.approx(0.005)
+    assert s["scopes"]["dfl.local"] == pytest.approx(0.050)
+    assert scopes.per_round(dict(s, rounds=2), "moe.experts") == \
+        pytest.approx(0.0025)
+    assert reader("optimizer_s_per_round")(dict(s, rounds=2)) is None
 
 
 def test_two_devices_average_their_phase_time():
