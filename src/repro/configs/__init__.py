@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + the paper's own CNN."""
+"""Architecture registry: the assigned configs + the paper's own CNN."""
 from __future__ import annotations
 
 from typing import Dict, List
@@ -16,6 +16,7 @@ from repro.configs import (
     jamba_1_5_large_398b,
     deepseek_coder_33b,
     phi3_5_moe_42b_a6_6b,
+    moonlight_16b_a3b,
 )
 
 _MODULES = [
@@ -29,6 +30,7 @@ _MODULES = [
     jamba_1_5_large_398b,
     deepseek_coder_33b,
     phi3_5_moe_42b_a6_6b,
+    moonlight_16b_a3b,
 ]
 
 REGISTRY: Dict[str, ArchConfig] = {m.ARCH.arch_id: m.ARCH for m in _MODULES}

@@ -51,7 +51,11 @@ from repro.core.substrate import (BatchedSubstrate, DenseSubstrate,
 from repro.core.topology import Topology
 
 PyTree = Any
-LossFn = Callable[[PyTree, PyTree, jax.Array], jnp.ndarray]
+# loss_fn(params, batch, key) -> (loss, counters): counters is a dict of
+# per-step scalars (e.g. a model's ``moe_rows``), summed over local steps
+# and nodes into the round's metrics. ``_with_counters`` also takes a loss
+# that returns the scalar alone, as one with no counters.
+LossFn = Callable[[PyTree, PyTree, jax.Array], Any]
 
 __all__ = [
     "DFLConfig",
@@ -193,11 +197,20 @@ def init_state(
     )
 
 
+def _with_counters(loss_fn: LossFn):
+    """``loss_fn`` as (loss, counters); a loss that returns the scalar
+    alone gets empty counters. The one place the scalar form is taken."""
+    def fn(params, batch, key):
+        out = loss_fn(params, batch, key)
+        return out if isinstance(out, tuple) else (out, {})
+    return fn
+
+
 def _local_updates(
     cfg: DFLConfig, loss_fn: LossFn, opt, sub: NodeSubstrate,
     params: PyTree, opt_state: PyTree, local_key: jax.Array, batches: PyTree,
     constrain, tau1=None, node_mask=None,
-) -> Tuple[PyTree, PyTree, jnp.ndarray]:
+) -> Tuple[PyTree, PyTree, jnp.ndarray, dict]:
     """tau1 per-node SGD steps (Alg. 1 l.4), engine-agnostic.
 
     Dense: batch leaves [tau1, N, ...], params [N, ...], sub.vmap = vmap.
@@ -221,15 +234,17 @@ def _local_updates(
     (``sub.select_nodes``), so the compiled program is mask-independent
     and the all-ones round is a bitwise select of the plain one. The loss
     metric averages over active nodes only.
+
+    The loss's counters are summed over the steps and over every node.
     """
-    grad_one = jax.value_and_grad(loss_fn)
+    grad_one = jax.value_and_grad(_with_counters(loss_fn), has_aux=True)
     params0, opt_state0 = params, opt_state
 
     def step(carry, inp):
         params, opt_state = carry
         batch_t, t = inp
         keys = sub.node_keys(jax.random.fold_in(local_key, t))
-        losses, grads = sub.vmap(grad_one)(params, batch_t, keys)
+        (losses, counters), grads = sub.vmap(grad_one)(params, batch_t, keys)
         grads = constrain(grads)
         with jax.named_scope("dfl.local.update"):
             updates, opt_state = sub.vmap(opt.update)(grads, opt_state,
@@ -237,20 +252,23 @@ def _local_updates(
             params = jax.tree_util.tree_map(
                 lambda p, u: (p + u).astype(p.dtype), params, updates)
             params = constrain(params)
-        return (params, opt_state), losses
+        return (params, opt_state), (losses, counters)
 
-    def finish(params, opt_state, per_node_loss):
+    def finish(params, opt_state, per_node_loss, counters):
+        counters = {k: sub.total_over_nodes(v) for k, v in counters.items()}
         if node_mask is None:
-            return params, opt_state, sub.mean_over_nodes(per_node_loss)
+            return (params, opt_state, sub.mean_over_nodes(per_node_loss),
+                    counters)
         params = sub.select_nodes(node_mask, params, params0)
         opt_state = sub.select_nodes(node_mask, opt_state, opt_state0)
         return params, opt_state, sub.masked_mean_over_nodes(
-            per_node_loss, node_mask)
+            per_node_loss, node_mask), counters
 
     if tau1 is None:
-        (params, opt_state), losses = jax.lax.scan(
+        (params, opt_state), (losses, counters) = jax.lax.scan(
             step, (params, opt_state), (batches, jnp.arange(cfg.tau1)))
-        return finish(params, opt_state, jnp.mean(losses, axis=0))
+        return finish(params, opt_state, jnp.mean(losses, axis=0),
+                      {k: jnp.sum(v, axis=0) for k, v in counters.items()})
 
     def batch_at(t):
         return jax.tree_util.tree_map(
@@ -261,18 +279,19 @@ def _local_updates(
     # yields the per-node loss accumulator's shape/dtype; the summation
     # order (l_0 + l_1 + ...) matches the static path's axis-0 reduce, so
     # dynamic and static rounds stay bitwise identical.
-    carry, loss_sum = step((params, opt_state),
-                           (batch_at(jnp.zeros((), jnp.int32)),
-                            jnp.zeros((), jnp.int32)))
+    carry, sums = step((params, opt_state),
+                       (batch_at(jnp.zeros((), jnp.int32)),
+                        jnp.zeros((), jnp.int32)))
 
     def body(t, acc):
-        carry, loss_sum = acc
-        carry, losses = step(carry, (batch_at(t), t))
-        return carry, loss_sum + losses
+        carry, sums = acc
+        carry, step_out = step(carry, (batch_at(t), t))
+        return carry, jax.tree_util.tree_map(jnp.add, sums, step_out)
 
-    (params, opt_state), loss_sum = jax.lax.fori_loop(
-        1, tau1, body, (carry, loss_sum))
-    return finish(params, opt_state, loss_sum / tau1.astype(loss_sum.dtype))
+    (params, opt_state), (loss_sum, counters) = jax.lax.fori_loop(
+        1, tau1, body, (carry, sums))
+    return finish(params, opt_state, loss_sum / tau1.astype(loss_sum.dtype),
+                  counters)
 
 
 def _communicate_plain(cfg: DFLConfig, sub: NodeSubstrate, params: PyTree,
@@ -375,7 +394,7 @@ def local_phase(
     executor, tests' pure-Python references) can compose the two round
     stages explicitly. ``node_mask`` is the substrate-LOCAL participation
     view (``sub.node_mask_local``). Returns (params', opt_state',
-    mean_loss).
+    mean_loss, counters).
     """
     constrain = constrain or (lambda t: t)
     with jax.named_scope("dfl.local"):
@@ -445,7 +464,7 @@ def round_body(
     else:
         mask_local = edge_mask = None
     local_key, comm_key = round_keys(rng, round_idx)
-    params, opt_state, mean_loss = local_phase(
+    params, opt_state, mean_loss, counters = local_phase(
         cfg, loss_fn, opt, sub, params, opt_state, local_key, batches,
         constrain, tau1=tau1, node_mask=mask_local)
     params, hat = gossip_phase(cfg, sub, params, hat, comm_key, round_idx,
@@ -453,6 +472,7 @@ def round_body(
     metrics = {
         "loss": mean_loss,
         "consensus_sq": sub.consensus_sq(params),
+        **counters,
     }
     return params, opt_state, hat, metrics
 
@@ -650,7 +670,7 @@ def pipeline_round_body(
         mask_local = None
     local_key, _ = round_keys(rng, round_idx)
     _, stale_comm_key = round_keys(rng, round_idx - 1)
-    z, opt_state, mean_loss = local_phase(
+    z, opt_state, mean_loss, counters = local_phase(
         cfg, loss_fn, opt, sub, params, opt_state, local_key, batches,
         constrain, tau1=tau1, node_mask=mask_local)
     g, hat_g = gossip_phase(cfg, sub, buf, hat, stale_comm_key,
@@ -667,6 +687,7 @@ def pipeline_round_body(
     metrics = {
         "loss": mean_loss,
         "consensus_sq": sub.consensus_sq(params),
+        **counters,
     }
     return params, opt_state, hat, z, metrics
 

@@ -172,6 +172,10 @@ class NodeSubstrate:
     def sum_per_node(self, x: jnp.ndarray) -> jnp.ndarray:
         raise NotImplementedError
 
+    def total_over_nodes(self, x: jnp.ndarray) -> jnp.ndarray:
+        """A per-node scalar summed over every node."""
+        raise NotImplementedError
+
     def mean_tree(self, tree: PyTree) -> PyTree:
         raise NotImplementedError
 
@@ -265,6 +269,9 @@ class DenseSubstrate(NodeSubstrate):
 
     def sum_per_node(self, x):
         return jnp.sum(x, axis=tuple(range(1, x.ndim)))
+
+    def total_over_nodes(self, x):
+        return jnp.sum(x, axis=0)
 
     def mean_tree(self, tree):
         return jax.tree_util.tree_map(
@@ -536,6 +543,9 @@ class ShardedSubstrate(NodeSubstrate):
 
     def sum_per_node(self, x):
         return jnp.sum(x)
+
+    def total_over_nodes(self, x):
+        return jax.lax.psum(x, self.axis)
 
     def mean_tree(self, tree):
         return jax.tree_util.tree_map(

@@ -552,7 +552,8 @@ def build_local_step(
     def local_step(params, opt_state, batch):
         def loss_one(p, b):
             return tf_lib.train_loss(p, b, cfg)
-        losses, grads = jax.vmap(jax.value_and_grad(loss_one))(params, batch)
+        (losses, _), grads = jax.vmap(
+            jax.value_and_grad(loss_one, has_aux=True))(params, batch)
         grads = constrain(grads)
         updates, opt_state = jax.vmap(opt.update)(grads, opt_state, params)
         params = jax.tree_util.tree_map(

@@ -1,5 +1,6 @@
-"""GQA attention: chunked (flash-style) training/prefill path + KV-cache
-decode path, with sliding windows, qk-norm, RoPE and cross-attention.
+"""GQA and latent (MLA) attention: chunked (flash-style) training/prefill
+path + KV-cache decode path, with sliding windows, qk-norm, RoPE and
+cross-attention.
 
 The chunked path never materializes the full [S, T] score matrix: it scans
 query chunks (optionally ``jax.checkpoint``ed so the backward pass recomputes
@@ -49,6 +50,23 @@ def attn_params(f: ParamFactory, cfg: ModelConfig, cross: bool = False) -> Dict:
     return p
 
 
+def mla_params(f: ParamFactory, cfg: ModelConfig) -> Dict:
+    """DeepSeek-V3 latent attention without q compression (q_lora_rank
+    null): a plain q projection, the compressed kv and the shared rope key
+    from one projection, the kv norm, and the kv up-projection."""
+    h_ax = "heads" if cfg.attn_shard == "heads" else None
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope_d, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                        cfg.v_head_dim)
+    return {
+        "wq": f.dense((d, h, nope + rope_d), ("embed", h_ax, None)),
+        "wkv_a": f.dense((d, r + rope_d), ("embed", None)),
+        "kv_norm": f.zeros((r,), (None,)),
+        "wkv_b": f.dense((r, h, nope + vd), (None, h_ax, None)),
+        "wo": f.dense((h, vd, d), (h_ax, None, "embed")),
+    }
+
+
 # ---------------------------------------------------------------------------
 # Chunked attention core
 # ---------------------------------------------------------------------------
@@ -65,7 +83,7 @@ def _pick_chunk(total: int, want: int) -> int:
 def chunked_attention(
     q: jnp.ndarray,                 # [B, S, H, hd]
     k: jnp.ndarray,                 # [B, T, KVH, hd]
-    v: jnp.ndarray,                 # [B, T, KVH, hd]
+    v: jnp.ndarray,                 # [B, T, KVH, vd]
     *,
     q_positions: jnp.ndarray,       # [S] absolute positions of queries
     kv_positions: jnp.ndarray,      # [T] absolute positions of keys (-1 = empty)
@@ -77,7 +95,7 @@ def chunked_attention(
     checkpoint: bool = False,
 ) -> jnp.ndarray:
     b, s, h, hd = q.shape
-    t, kvh = k.shape[1], k.shape[2]
+    t, kvh, vd = k.shape[1], k.shape[2], v.shape[-1]
     g = h // kvh
     qc = _pick_chunk(s, q_chunk)
     kc = _pick_chunk(t, kv_chunk)
@@ -107,11 +125,11 @@ def chunked_attention(
                           preferred_element_type=jnp.float32)
         out1 = out1 / jnp.maximum(l, 1e-20)     # l: [b,n,g,q,1]
         return (out1.transpose(0, 3, 1, 2, 4)
-                .reshape(b, s, h, hd).astype(q.dtype))
+                .reshape(b, s, h, vd).astype(q.dtype))
 
     qr = q.reshape(b, nq, qc, kvh, g, hd).transpose(1, 0, 2, 3, 4, 5)
     kr = k.reshape(b, nk, kc, kvh, hd).transpose(1, 0, 2, 3, 4)
-    vr = v.reshape(b, nk, kc, kvh, hd).transpose(1, 0, 2, 3, 4)
+    vr = v.reshape(b, nk, kc, kvh, vd).transpose(1, 0, 2, 3, 4)
     qp = q_positions.reshape(nq, qc)
     kp = kv_positions.reshape(nk, kc)
 
@@ -119,11 +137,11 @@ def chunked_attention(
         # qblk [B, qc, KVH, G, hd]; qpos [qc]
         m0 = jnp.full((b, kvh, g, qc), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kvh, g, qc), jnp.float32)
-        a0 = jnp.zeros((b, kvh, g, qc, hd), jnp.float32)
+        a0 = jnp.zeros((b, kvh, g, qc, vd), jnp.float32)
 
         def kv_step(carry, inp):
             m, l, acc = carry
-            kblk, vblk, kpos = inp  # [B,kc,KVH,hd], [B,kc,KVH,hd], [kc]
+            kblk, vblk, kpos = inp  # [B,kc,KVH,hd], [B,kc,KVH,vd], [kc]
             s_ = jnp.einsum(
                 "bqngd,bknd->bngqk", qblk, kblk,
                 preferred_element_type=jnp.float32,
@@ -149,15 +167,15 @@ def chunked_attention(
 
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), (kr, vr, kp))
         out = acc / jnp.maximum(l, 1e-20)[..., None]
-        # [B,KVH,G,qc,hd] -> [B,qc,KVH*G,hd]
-        return out.transpose(0, 3, 1, 2, 4).reshape(b, qc, h, hd)
+        # [B,KVH,G,qc,vd] -> [B,qc,KVH*G,vd]
+        return out.transpose(0, 3, 1, 2, 4).reshape(b, qc, h, vd)
 
     if checkpoint:
         q_block = jax.checkpoint(q_block)
 
     out = jax.lax.map(lambda args: q_block(*args), (qr, qp))
-    # [nq, B, qc, H, hd] -> [B, S, H, hd]
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hd).astype(q.dtype)
+    # [nq, B, qc, H, vd] -> [B, S, H, vd]
+    return out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, vd).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -168,7 +186,7 @@ def chunked_attention(
 def _project_q(p, x, cfg: ModelConfig, positions, theta):
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     if "q_norm" in p:
-        q = rms_norm(q, p["q_norm"])
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
     return rope(q, positions[None, :], theta)
 
 
@@ -176,9 +194,39 @@ def _project_kv(p, x, cfg: ModelConfig, positions, theta):
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(x.dtype))
     if "k_norm" in p:
-        k = rms_norm(k, p["k_norm"])
+        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
     k = rope(k, positions[None, :], theta)
     return k, v
+
+
+def _project_mla(p, x, cfg: ModelConfig, positions, theta):
+    """q and k of qk_nope + qk_rope dims per head, v of v_head_dim. The
+    rope part of k is one head broadcast to every head. ``rope`` rotates
+    the two halves of the rope dims, where DeepSeek-V3's code rotates
+    interleaved pairs: a fixed permutation of the rope columns of ``wq``
+    and ``wkv_a``."""
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+    q = jnp.concatenate(
+        [q[..., :nope], rope(q[..., nope:], positions[None, :], theta)], -1)
+    kv_a = jnp.einsum("bsd,dk->bsk", x, p["wkv_a"].astype(x.dtype))
+    latent = rms_norm(kv_a[..., :r], p["kv_norm"], cfg.rms_eps)
+    k_rope = rope(kv_a[..., None, r:], positions[None, :], theta)
+    kv = jnp.einsum("bsr,rhk->bshk", latent, p["wkv_b"].astype(x.dtype))
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1]
+                                  + (k_rope.shape[-1],))], -1)
+    return q, k, v
+
+
+def _project(p, x, cfg: ModelConfig, spec: LayerSpec, positions):
+    theta = spec.rope_theta or cfg.rope_theta
+    if spec.mixer == "mla":
+        return _project_mla(p, x, cfg, positions, theta)
+    q = _project_q(p, x, cfg, positions, theta)
+    k, v = _project_kv(p, x, cfg, positions, theta)
+    return q, k, v
 
 
 def self_attention(
@@ -191,9 +239,7 @@ def self_attention(
     checkpoint: bool = False,
     causal: bool = True,
 ) -> jnp.ndarray:
-    theta = spec.rope_theta or cfg.rope_theta
-    q = _project_q(p, x, cfg, positions, theta)
-    k, v = _project_kv(p, x, cfg, positions, theta)
+    q, k, v = _project(p, x, cfg, spec, positions)
     out = chunked_attention(
         q, k, v,
         q_positions=positions,
@@ -211,16 +257,21 @@ def self_attention(
 def init_kv_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
                   max_len: int, abstract: bool = False) -> Dict:
     size = min(spec.window, max_len) if spec.window else max_len
-    shape_kv = (batch, size, cfg.num_kv_heads, cfg.head_dim)
+    if spec.mixer == "mla":     # the expanded per-head k and v
+        shape_k = (batch, size, cfg.num_heads,
+                   cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+        shape_v = (batch, size, cfg.num_heads, cfg.v_head_dim)
+    else:
+        shape_k = shape_v = (batch, size, cfg.num_kv_heads, cfg.head_dim)
     if abstract:
         return {
-            "k": jax.ShapeDtypeStruct(shape_kv, cfg.dtype),
-            "v": jax.ShapeDtypeStruct(shape_kv, cfg.dtype),
+            "k": jax.ShapeDtypeStruct(shape_k, cfg.dtype),
+            "v": jax.ShapeDtypeStruct(shape_v, cfg.dtype),
             "pos": jax.ShapeDtypeStruct((size,), jnp.int32),
         }
     return {
-        "k": jnp.zeros(shape_kv, cfg.dtype),
-        "v": jnp.zeros(shape_kv, cfg.dtype),
+        "k": jnp.zeros(shape_k, cfg.dtype),
+        "v": jnp.zeros(shape_v, cfg.dtype),
         "pos": jnp.full((size,), -1, jnp.int32),
     }
 
@@ -235,9 +286,7 @@ def prefill_attention(
     positions: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, Dict]:
     """Full-sequence attention that also populates the KV cache."""
-    theta = spec.rope_theta or cfg.rope_theta
-    q = _project_q(p, x, cfg, positions, theta)
-    k, v = _project_kv(p, x, cfg, positions, theta)
+    q, k, v = _project(p, x, cfg, spec, positions)
     out = chunked_attention(
         q, k, v,
         q_positions=positions,
@@ -277,10 +326,8 @@ def decode_attention(
     *,
     position: jnp.ndarray,          # scalar int32 current position
 ) -> Tuple[jnp.ndarray, Dict]:
-    theta = spec.rope_theta or cfg.rope_theta
     pos_arr = position[None]
-    q = _project_q(p, x, cfg, pos_arr, theta)
-    k_new, v_new = _project_kv(p, x, cfg, pos_arr, theta)
+    q, k_new, v_new = _project(p, x, cfg, spec, pos_arr)
     size = cache["k"].shape[1]
     slot = position % size if spec.window else position
     cache = {

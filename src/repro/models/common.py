@@ -27,7 +27,7 @@ PyTree = Any
 class LayerSpec:
     """One layer inside the repeating period block of a stack."""
 
-    mixer: str = "attn"          # attn | mamba
+    mixer: str = "attn"          # attn | mla | mamba
     ffn: str = "mlp"             # mlp | moe | none
     window: int = 0              # sliding-window size; 0 = full attention
     cross_attn: bool = False     # adds a cross-attention sub-block
@@ -51,12 +51,27 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 1e4
     logit_softcap: float = 0.0
+    rms_eps: float = 1e-6
+    # latent attention (mixer "mla", DeepSeek-V2/V3): q and k have
+    # qk_nope_head_dim + qk_rope_head_dim dims, v has v_head_dim; k's rope
+    # part is one head shared by all heads; k_nope and v come from a
+    # kv_lora_rank-dim compressed kv
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # repeating layer pattern; default = uniform (attn + cfg-default ffn)
     pattern: Tuple[LayerSpec, ...] = ()
-    # MoE
+    # MoE: the router scores router_experts (0 = num_experts) and picks
+    # experts_per_token; this layer holds and computes the first
+    # num_experts of them, each of width expert_d_ff (0 = d_ff)
     num_experts: int = 0
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    router_experts: int = 0
+    expert_d_ff: int = 0
+    num_shared_experts: int = 0
+    router_scoring: str = "softmax"      # softmax | sigmoid
+    routed_scaling: float = 1.0
     router_aux_coef: float = 0.01
     # SSM (mamba-1)
     ssm_state: int = 0
@@ -102,6 +117,14 @@ class ModelConfig:
         return self.num_layers // self.period
 
     @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def expert_width(self) -> int:
+        return self.expert_d_ff or self.d_ff
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -136,7 +159,7 @@ class ModelConfig:
         # expert weights: ffn mlp tensors in moe layers.
         specs = self.layer_specs()
         n_moe = sum(1 for s in specs if s.ffn == "moe")
-        per_expert = 3 * self.d_model * self.d_ff
+        per_expert = 3 * self.d_model * self.expert_width
         expert_total = n_moe * self.num_experts * per_expert
         expert_active = n_moe * self.experts_per_token * per_expert
         return total - expert_total + expert_active

@@ -8,7 +8,7 @@ a 100-layer model compiles as fast as a 2-layer one).
 
 Entry points:
   init_params(cfg, key)                      -> (params, logical_axes)
-  train_loss(params, batch, cfg, rng)        -> scalar loss (+aux)
+  train_loss(params, batch, cfg, rng)        -> (scalar loss, counters)
   prefill(params, batch, cfg)                -> (last_logits, DecodeState)
   decode_step(params, state, tokens, cfg)    -> (logits, DecodeState)
   init_decode_state(cfg, batch, max_len)     -> DecodeState (zeros/abstract)
@@ -57,6 +57,8 @@ def _layer_params(f: ParamFactory, cfg: ModelConfig, spec: LayerSpec) -> Dict:
     p: Dict[str, Any] = {"ln1": f.zeros((cfg.d_model,), ("embed",))}
     if spec.mixer == "attn":
         p["mixer"] = attn_lib.attn_params(f, cfg)
+    elif spec.mixer == "mla":
+        p["mixer"] = attn_lib.mla_params(f, cfg)
     elif spec.mixer == "mamba":
         p["mixer"] = mamba_lib.mamba_params(f, cfg)
     else:
@@ -151,15 +153,33 @@ def _encode_memory(params: Dict, memory: jnp.ndarray, cfg: ModelConfig,
 
     def enc_layer(h, layer_p):
         h = h + attn_lib.self_attention(
-            layer_p["mixer"], rms_norm(h, layer_p["ln1"]), cfg, enc_spec,
+            layer_p["mixer"], rms_norm(h, layer_p["ln1"], cfg.rms_eps), cfg,
+            enc_spec,
             positions=positions, checkpoint=checkpoint, causal=False)
-        h = h + swiglu(rms_norm(h, layer_p["ln2"]), layer_p["ffn"]["w_gate"],
+        h = h + swiglu(rms_norm(h, layer_p["ln2"], cfg.rms_eps),
+                       layer_p["ffn"]["w_gate"],
                        layer_p["ffn"]["w_up"], layer_p["ffn"]["w_down"])
         return shard_hidden(h), None
 
     body = jax.checkpoint(enc_layer) if checkpoint else enc_layer
     mem, _ = jax.lax.scan(body, shard_hidden(mem), params["encoder"])
-    return rms_norm(mem, params["encoder_norm"])
+    return rms_norm(mem, params["encoder_norm"], cfg.rms_eps)
+
+
+def _ffn_block(layer_p: Dict, spec: LayerSpec, h: jnp.ndarray,
+               cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """The layer's feed-forward sub-block; returns (h, its counters: the
+    MoE balance loss ``aux`` and held (token, expert) pairs ``moe_rows``,
+    none for a dense one)."""
+    if spec.ffn == "none":
+        return h, {}
+    x2 = rms_norm(h, layer_p["ln2"], cfg.rms_eps)
+    if spec.ffn == "mlp":
+        return h + swiglu(x2, layer_p["ffn"]["w_gate"], layer_p["ffn"]["w_up"],
+                          layer_p["ffn"]["w_down"]), {}
+    with jax.named_scope("model.moe"):
+        out, aux, rows = moe_lib.moe_ffn(layer_p["ffn"], x2, cfg)
+    return h + out, {"aux": aux, "moe_rows": rows}
 
 
 def _apply_layer(
@@ -170,32 +190,27 @@ def _apply_layer(
     positions: jnp.ndarray,
     memory: Optional[jnp.ndarray],
     checkpoint: bool,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    aux = jnp.zeros((), jnp.float32)
-    x = rms_norm(h, layer_p["ln1"])
+) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    x = rms_norm(h, layer_p["ln1"], cfg.rms_eps)
     if spec.mixer == "attn":
         mixed = attn_lib.self_attention(
             layer_p["mixer"], x, cfg, spec, positions=positions,
             checkpoint=checkpoint)
+    elif spec.mixer == "mla":
+        with jax.named_scope("model.mla"):
+            mixed = attn_lib.self_attention(
+                layer_p["mixer"], x, cfg, spec, positions=positions,
+                checkpoint=checkpoint)
     else:
         mixed = mamba_lib.mamba_mixer(layer_p["mixer"], x, cfg,
                                       checkpoint=checkpoint)
     h = h + mixed
     if spec.cross_attn:
         assert memory is not None, f"{cfg.name}: cross-attn layer needs memory"
-        xc = rms_norm(h, layer_p["ln_cross"])
+        xc = rms_norm(h, layer_p["ln_cross"], cfg.rms_eps)
         h = h + attn_lib.cross_attention(layer_p["cross"], xc, memory, cfg,
                                          checkpoint=checkpoint)
-    if spec.ffn == "mlp":
-        x2 = rms_norm(h, layer_p["ln2"])
-        h = h + swiglu(x2, layer_p["ffn"]["w_gate"], layer_p["ffn"]["w_up"],
-                       layer_p["ffn"]["w_down"])
-    elif spec.ffn == "moe":
-        x2 = rms_norm(h, layer_p["ln2"])
-        out, aux_l = moe_lib.moe_ffn(layer_p["ffn"], x2, cfg)
-        h = h + out
-        aux = aux + aux_l
-    return h, aux
+    return _ffn_block(layer_p, spec, h, cfg)
 
 
 def forward(
@@ -205,8 +220,12 @@ def forward(
     *,
     memory: Optional[jnp.ndarray] = None,  # [B, M, mem_dim]
     checkpoint: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (hidden [B,S,D], moe_aux scalar)."""
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Returns (hidden [B,S,D], counters summed over layers: ``aux``, the
+    MoE balance loss, and, for a model with MoE layers, ``moe_rows``).
+
+    Under ``checkpoint`` each layer is rematerialised in the backward pass:
+    a one-layer period as the scan's body, a longer one layer by layer."""
     checkpoint = cfg.remat if checkpoint is None else checkpoint
     h = params["embed"].astype(cfg.dtype)[tokens]
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
@@ -214,22 +233,30 @@ def forward(
     if cfg.has_memory_input:
         assert memory is not None, f"{cfg.name} requires memory input"
         mem = _encode_memory(params, memory, cfg, checkpoint)
+    zero = jnp.zeros((), jnp.float32)
+    counters = {"aux": zero}
+    if any(spec.ffn == "moe" for spec in cfg.pattern):
+        counters["moe_rows"] = zero
+    layer_remat = checkpoint and cfg.period > 1
+    apply = functools.partial(_apply_layer, cfg=cfg, positions=positions,
+                              memory=mem, checkpoint=checkpoint)
+    if layer_remat:
+        apply = jax.checkpoint(apply, static_argnums=(1,))
 
     def period_body(carry, period_params):
-        h, aux = carry
+        h, counters = carry
         for pos, spec in enumerate(cfg.pattern):
-            h, aux_l = _apply_layer(period_params[pos], spec, h, cfg,
-                                    positions, mem, checkpoint)
-            aux = aux + aux_l
-        return (shard_hidden(h), aux), None
+            h, layer_counters = apply(period_params[pos], spec, h)
+            counters = {k: v + layer_counters[k] if k in layer_counters
+                        else v for k, v in counters.items()}
+        return (shard_hidden(h), counters), None
 
-    body = jax.checkpoint(period_body) if checkpoint else period_body
-    (h, aux), _ = jax.lax.scan(
-        body, (shard_hidden(h), jnp.zeros((), jnp.float32)),
-        tuple(params["blocks"])
-    )
-    h = rms_norm(h, params["final_norm"])
-    return h, aux
+    body = (jax.checkpoint(period_body) if checkpoint and not layer_remat
+            else period_body)
+    (h, counters), _ = jax.lax.scan(
+        body, (shard_hidden(h), counters), tuple(params["blocks"]))
+    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
+    return h, counters
 
 
 def _unembed(params: Dict, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -244,13 +271,18 @@ def train_loss(
     batch: Dict[str, jnp.ndarray],
     cfg: ModelConfig,
     rng: Optional[jax.Array] = None,
-) -> jnp.ndarray:
-    """Next-token cross-entropy, chunked over the sequence so the full
-    [B,S,V] logit tensor never materializes."""
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """(Next-token cross-entropy, the model's counters). The cross-entropy
+    is chunked over the sequence so the full [B,S,V] logit tensor never
+    materializes. The MoE balance loss, times ``router_aux_coef``, is in
+    the gradient and not in the value. A model with MoE layers counts
+    ``moe_rows``; otherwise the counters are empty."""
     del rng
     tokens = batch["tokens"]
     labels = batch["labels"]
-    h, aux = forward(params, tokens, cfg, memory=batch.get("memory"))
+    h, counters = forward(params, tokens, cfg, memory=batch.get("memory"))
+    aux = counters.pop("aux")
+    aux = aux - jax.lax.stop_gradient(aux)
     h = shard_hidden(h)
     b, s, d = h.shape
     v = pad_vocab(cfg.vocab_size)
@@ -272,7 +304,7 @@ def train_loss(
     body = jax.checkpoint(chunk_loss) if cfg.remat else chunk_loss
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (hc, lc))
     loss = total / (b * s)
-    return loss + cfg.router_aux_coef * aux
+    return loss + cfg.router_aux_coef * aux, counters
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +323,7 @@ def init_decode_state(
 ) -> DecodeState:
     caches = []
     for spec in cfg.pattern:
-        if spec.mixer == "attn":
+        if spec.mixer in ("attn", "mla"):
             one = attn_lib.init_kv_cache(cfg, spec, batch, max_len, abstract)
         else:
             one = mamba_lib.init_mamba_state(cfg, batch, abstract)
@@ -333,8 +365,8 @@ def prefill(
         caches_out = []
         for pos_idx, spec in enumerate(cfg.pattern):
             layer_p = period_params[pos_idx]
-            x = rms_norm(h, layer_p["ln1"])
-            if spec.mixer == "attn":
+            x = rms_norm(h, layer_p["ln1"], cfg.rms_eps)
+            if spec.mixer in ("attn", "mla"):
                 cache0 = attn_lib.init_kv_cache(cfg, spec, b, max_len)
                 mixed, cache = attn_lib.prefill_attention(
                     layer_p["mixer"], x, cfg, spec, cache0, positions=positions)
@@ -343,22 +375,15 @@ def prefill(
                     layer_p["mixer"], x, cfg, return_state=True)
             h = h + mixed
             if spec.cross_attn:
-                xc = rms_norm(h, layer_p["ln_cross"])
+                xc = rms_norm(h, layer_p["ln_cross"], cfg.rms_eps)
                 h = h + attn_lib.cross_attention(layer_p["cross"], xc, mem, cfg)
-            if spec.ffn in ("mlp", "moe"):
-                x2 = rms_norm(h, layer_p["ln2"])
-                if spec.ffn == "mlp":
-                    h = h + swiglu(x2, layer_p["ffn"]["w_gate"],
-                                   layer_p["ffn"]["w_up"], layer_p["ffn"]["w_down"])
-                else:
-                    out, _ = moe_lib.moe_ffn(layer_p["ffn"], x2, cfg)
-                    h = h + out
+            h, _ = _ffn_block(layer_p, spec, h, cfg)
             caches_out.append(cache)
         return shard_hidden(h), tuple(caches_out)
 
     h, caches = jax.lax.scan(scan_body, shard_hidden(h),
                              tuple(params["blocks"]))
-    h = rms_norm(h, params["final_norm"])
+    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
     last_logits = _unembed(params, h[:, -1], cfg)
     state = DecodeState(
         caches=caches, memory=mem,
@@ -383,30 +408,23 @@ def decode_step(
         for pos_idx, spec in enumerate(cfg.pattern):
             layer_p = period_params[pos_idx]
             cache = caches[pos_idx]
-            x = rms_norm(h, layer_p["ln1"])
-            if spec.mixer == "attn":
+            x = rms_norm(h, layer_p["ln1"], cfg.rms_eps)
+            if spec.mixer in ("attn", "mla"):
                 mixed, cache = attn_lib.decode_attention(
                     layer_p["mixer"], x, cfg, spec, cache, position=position)
             else:
                 mixed, cache = mamba_lib.mamba_decode(layer_p["mixer"], x, cfg, cache)
             h = h + mixed
             if spec.cross_attn:
-                xc = rms_norm(h, layer_p["ln_cross"])
+                xc = rms_norm(h, layer_p["ln_cross"], cfg.rms_eps)
                 h = h + attn_lib.cross_attention(layer_p["cross"], xc, mem, cfg)
-            if spec.ffn in ("mlp", "moe"):
-                x2 = rms_norm(h, layer_p["ln2"])
-                if spec.ffn == "mlp":
-                    h = h + swiglu(x2, layer_p["ffn"]["w_gate"],
-                                   layer_p["ffn"]["w_up"], layer_p["ffn"]["w_down"])
-                else:
-                    out, _ = moe_lib.moe_ffn(layer_p["ffn"], x2, cfg)
-                    h = h + out
+            h, _ = _ffn_block(layer_p, spec, h, cfg)
             caches_out.append(cache)
         return h, tuple(caches_out)
 
     h, new_caches = jax.lax.scan(
         scan_body, h, (tuple(params["blocks"]), state.caches))
-    h = rms_norm(h, params["final_norm"])
+    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
     logits = _unembed(params, h[:, -1], cfg)
     new_state = DecodeState(
         caches=new_caches, memory=mem, position=position + 1)

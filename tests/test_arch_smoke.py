@@ -40,8 +40,8 @@ def test_reduced_train_step(arch_id):
     assert n_params == n_axes
     batch = _smoke_batch(cfg)
 
-    loss, grads = jax.value_and_grad(
-        lambda p: train_loss(p, batch, cfg))(params)
+    (loss, _), grads = jax.value_and_grad(
+        lambda p: train_loss(p, batch, cfg), has_aux=True)(params)
     assert loss.shape == ()
     assert bool(jnp.isfinite(loss)), f"{arch_id}: non-finite loss"
     for path, g in jax.tree_util.tree_flatten_with_path(grads)[0]:
@@ -103,6 +103,15 @@ def test_full_config_matches_assignment(arch_id):
                                      num_kv_heads=8, d_ff=6400,
                                      vocab_size=32064, num_experts=16,
                                      experts_per_token=2),
+        "moonlight-16b-a3b": dict(num_layers=27, d_model=2048, num_heads=16,
+                                  d_ff=11264, vocab_size=163840,
+                                  kv_lora_rank=512, qk_nope_head_dim=128,
+                                  qk_rope_head_dim=64, v_head_dim=128,
+                                  num_experts=64, experts_per_token=6,
+                                  expert_d_ff=1408, num_shared_experts=2,
+                                  router_scoring="sigmoid",
+                                  routed_scaling=2.446, rms_eps=1e-5,
+                                  rope_theta=50_000.0, tie_embeddings=False),
     }[arch_id]
     for k, v in expected.items():
         assert getattr(m, k) == v, f"{arch_id}.{k}: {getattr(m, k)} != {v}"

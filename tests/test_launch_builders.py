@@ -97,9 +97,9 @@ def test_batch_not_divisible_raises(mesh):
 
 def test_dryrun_runnable_combos_count():
     total = sum(len(a.shapes()) for a in REGISTRY.values())
-    assert total == 33  # 40 assigned minus 7 documented long_500k skips
+    assert total == 36  # 44 assigned minus 8 documented long_500k skips
     skipped = sum(len(a.skip_shapes) for a in REGISTRY.values())
-    assert skipped == 7
+    assert skipped == 8
     for a in REGISTRY.values():
         if a.skip_shapes:
             assert a.skip_reason

@@ -81,7 +81,7 @@ def stale_reference(cfg, opt, state, round_batches, taus):
         lk, _ = round_keys(rng, r)
         bt = np.zeros((cfg.tau1,) + b.shape[1:], np.float32)
         bt[: b.shape[0]] = np.asarray(b)
-        z, opt_state, loss = local_phase(
+        z, opt_state, loss, _ = local_phase(
             cfg, noisy_loss, opt, sub, params, opt_state, lk,
             jnp.asarray(bt), tau1=jnp.asarray(int(t1), jnp.int32))
         losses.append(float(loss))
